@@ -177,12 +177,9 @@ class NodeGroup:
         self.health.inject_fault(kind, after=after, timeout=timeout)
 
     def mesh(self, axes=("data",), axis_sizes: Optional[Sequence[int]] = None):
-        import numpy as _np
-        devs = _np.array(self.devices)
-        if len(axes) == 1:
-            return jax.sharding.Mesh(devs, axes)
+        from repro.models.sharding import make_mesh
         shape = mesh_axis_sizes(len(self.devices), len(axes), axis_sizes)
-        return jax.sharding.Mesh(devs.reshape(shape), axes)
+        return make_mesh(shape, axes, self.devices)
 
 
 @dataclass

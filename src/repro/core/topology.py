@@ -383,7 +383,10 @@ class HeteroRuntime:
                  max_len: Optional[int] = None,
                  payload_bytes_per_item: Optional[float] = None) -> TaskSpec:
         """Register a workload in the session's multi-task registry: one
-        slot-based engine per node group, sharing jitted programs.
+        slot-based engine per node group, sharing jitted programs.  Each
+        engine is pinned to its group's first device (params, KV cache,
+        decode state and programs); a group that spans several devices
+        still decodes on its first one.
         ``max_new`` caps every request of this task (requests asking for
         more are clamped at dispatch)."""
         if name in self.tasks:
@@ -430,7 +433,8 @@ class HeteroRuntime:
                                           overlap_admission=overlap,
                                           prefill_worker=worker,
                                           prefix_cache=pcache,
-                                          share_from=first)
+                                          share_from=first,
+                                          device=grp.devices[0])
             engines[grp.name] = eng
             first = first or eng
         payload = payload_bytes_per_item

@@ -1,10 +1,8 @@
 """jit'd public wrappers for the Pallas kernels + fused serving hot-path ops.
 
-``interpret`` is auto-detected per backend: on a real TPU the kernels
-compile through Mosaic; everywhere else (CPU CI, GPU) they run in
-interpreter mode for correctness.  ``REPRO_PALLAS_INTERPRET=0/1``
-overrides the detection either way (e.g. force-interpret on a TPU while
-debugging a kernel).
+``interpret`` follows the backend: on a TPU the kernels always compile
+through Mosaic; everywhere else (CPU CI, GPU) they run in interpreter
+mode for correctness.  Nothing can force interpret mode on a TPU.
 
 ``admit_slots`` is not a Pallas kernel — it is the XLA-fused admission
 splice the continuous serving engine dispatches at macro-step boundaries:
@@ -24,26 +22,18 @@ and the multi-GiB cache is never regathered at an admission boundary.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
-
-def default_interpret() -> bool:
-    """Env override first, then backend auto-detection (TPU → compiled)."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env != "0"
-    from repro.kernels.decode_attention import auto_interpret
-    return auto_interpret()
+from repro.kernels.decode_attention import auto_interpret
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
 def masked_compact(tokens, mask, capacity: int):
     from repro.kernels.masked_compact import masked_compact_pallas
     return masked_compact_pallas(tokens, mask, capacity,
-                                 interpret=default_interpret())
+                                 interpret=auto_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("window",))
@@ -51,7 +41,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0):
     from repro.kernels.decode_attention import decode_attention_pallas
     return decode_attention_pallas(q, k_cache, v_cache, cache_len,
                                    window=window,
-                                   interpret=default_interpret())
+                                   interpret=auto_interpret())
 
 
 def admit_state(cur_tok, lengths, remaining, done, slot_ids, last_logits,
@@ -140,7 +130,6 @@ def splice_blocks(dst, src, slot_ids):
     P = src.shape[2]
 
     from jax.sharding import PartitionSpec as Pspec
-    from repro.models.sharding import shard_map_compat
     baxes = tuple(a for a in ("pod", "data") if a in mesh.shape) \
         if lay.bspec is not None else ()
 
@@ -172,7 +161,7 @@ def splice_blocks(dst, src, slot_ids):
             d = jnp.where(mine, d.at[:, local_b].set(new), d)
         return d
 
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(Pspec(None, lay.bspec, lay.sspec, lay.hspec, None),
                   Pspec(None, None, None, lay.hspec, None), Pspec()),
@@ -184,10 +173,10 @@ def splice_blocks(dst, src, slot_ids):
 @jax.jit
 def ssm_scan(decay, bx, h0):
     from repro.kernels.ssm_scan import ssm_scan_pallas
-    return ssm_scan_pallas(decay, bx, h0, interpret=default_interpret())
+    return ssm_scan_pallas(decay, bx, h0, interpret=auto_interpret())
 
 
 @jax.jit
 def grouped_ffn(buf, wg, wu, wd):
     from repro.kernels.grouped_ffn import grouped_ffn_pallas
-    return grouped_ffn_pallas(buf, wg, wu, wd, interpret=default_interpret())
+    return grouped_ffn_pallas(buf, wg, wu, wd, interpret=auto_interpret())

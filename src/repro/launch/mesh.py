@@ -20,6 +20,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.models.sharding import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
@@ -31,12 +33,12 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             f"need {n} devices for the production mesh, have {len(devs)} — "
             "the dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             "before any jax import")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return make_mesh(shape, axes, devs[:n])
 
 
 def make_smoke_mesh() -> Mesh:
     """1-device mesh with the same axis names (tests on this container)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------

@@ -37,6 +37,7 @@ import numpy as np
 import repro.core as C
 from repro.configs.base import get_config, list_configs, reduced
 from repro.data.pipeline import request_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serving.engine import ServeRequest, ServingEngine
 
@@ -375,6 +376,7 @@ def main():
                     help="P_available floor (W) under the power envelope")
     args = ap.parse_args()
     nodes = args.nodes or (2 if args.topology == "pair" else 3)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -21,7 +21,7 @@ from repro.configs.base import get_config, list_configs, reduced
 from repro.data.pipeline import DataConfig, synthetic_lm_batches
 from repro.launch.mesh import data_shardings, params_shardings, replicated
 from repro.models import model as M
-from repro.models.sharding import activation_sharding
+from repro.models.sharding import activation_sharding, make_mesh
 from repro.training.checkpoint import save_checkpoint
 from repro.training.optimizer import OptimizerConfig, init_opt_state
 from repro.training.train import make_train_step
@@ -34,7 +34,7 @@ def build_mesh():
         if n % cand == 0 and cand <= n:
             model = cand
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def main():

@@ -245,8 +245,7 @@ def cache_update(cache, new, index):
             return jnp.where(mine, upd, c)
         idx_spec = P()
 
-    from repro.models.sharding import shard_map_compat
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(lay.bspec, lay.sspec, lay.hspec, None),
                   P(lay.bspec, None, lay.hspec, None), idx_spec),

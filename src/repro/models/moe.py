@@ -159,8 +159,7 @@ def _moe_shardmap(params, x, cfg, mesh, *, f_parallel: bool = False):
                    P(None, "model", None))
     else:
         w_specs = (P("model"), P("model"), P("model"))
-    from repro.models.sharding import shard_map_compat
-    y, aux = shard_map_compat(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), *w_specs, P(bspec, None, None)),
         out_specs=(P(bspec, None, None), P()),

@@ -18,7 +18,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 _STATE = {"mesh": None}
 
@@ -38,6 +38,19 @@ def active_mesh():
     return _STATE["mesh"]
 
 
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
+    """THE mesh constructor: every mesh in the program has Auto axes
+    (``jax.make_mesh`` defaults to Explicit axes, under which the model's
+    ``with_sharding_constraint`` hints in :func:`constrain` are rejected).
+    ``devices`` (default: the first ``prod(shape)`` local devices) are laid
+    out in the order given."""
+    n = int(np.prod(shape))
+    devs = list(jax.devices())[:n] if devices is None else list(devices)
+    return jax.sharding.Mesh(np.asarray(devs).reshape(tuple(shape)),
+                             tuple(axes),
+                             axis_types=(AxisType.Auto,) * len(axes))
+
+
 def scaleout_mesh(devices=None, axes: Tuple[str, ...] = ("data", "model")):
     """Balanced ("data","model") mesh over the local (or given) devices —
     the emulated multi-host harness's mesh constructor
@@ -46,10 +59,7 @@ def scaleout_mesh(devices=None, axes: Tuple[str, ...] = ("data", "model")):
     node-group sub-meshes, so 8 devices give (4, 2), 64 give (8, 8)."""
     from repro.core.offload import mesh_axis_sizes
     devs = list(jax.devices()) if devices is None else list(devices)
-    if len(axes) == 1:
-        return jax.sharding.Mesh(np.array(devs), axes)
-    shape = mesh_axis_sizes(len(devs), len(axes))
-    return jax.sharding.Mesh(np.array(devs).reshape(shape), axes)
+    return make_mesh(mesh_axis_sizes(len(devs), len(axes)), axes, devs)
 
 
 def replicated_sharding(mesh):
@@ -75,18 +85,6 @@ def put_replicated(tree, mesh=None):
         return tree
     s = replicated_sharding(mesh)
     return jax.tree.map(lambda x: jax.device_put(x, s), tree)
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """`jax.shard_map` moved out of jax.experimental over several releases
-    and renamed `check_rep` -> `check_vma` on the way; dispatch to whichever
-    this jax provides so pinned CI (0.4.x) and newer toolchains both work."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
 
 
 class SeqShardLayout(NamedTuple):

@@ -46,6 +46,7 @@ bit-identical streams — ``prefill_fallbacks`` records the recoveries.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 from collections import deque
@@ -668,7 +669,8 @@ class ContinuousServingEngine:
                  async_dispatch: bool = True,
                  prefill_worker: Optional[Any] = None,
                  prefix_cache: Optional[Any] = None,
-                 share_from: Optional["ContinuousServingEngine"] = None):
+                 share_from: Optional["ContinuousServingEngine"] = None,
+                 device: Optional[Any] = None):
         """`share_from`: another engine over the SAME cfg whose jitted
         prefill/step/slot-write/decode-loop programs this one reuses —
         jax.jit caches per function object, so sibling node-group engines
@@ -700,8 +702,19 @@ class ContinuousServingEngine:
         ``async_dispatch`` (default True, overlapped path): launch fused
         decode programs on a background thread so ``t_dispatch_s``
         measures the host-side launch tax and the device execution lands
-        in ``t_await_s`` (see :class:`_DecodeLauncher`)."""
-        self.cfg, self.params = cfg, params
+        in ``t_await_s`` (see :class:`_DecodeLauncher`).
+
+        ``device``: pin the engine to one device (a node group's chip):
+        the params are committed there, and the KV cache, the decode
+        state, every program the engine launches and every prefill block
+        it admits live there too.  None keeps JAX's default placement.
+        Inside an ``activation_sharding`` mesh the programs run mesh-wide
+        and the pin is dropped (the PrefillWorker does the same)."""
+        from repro.models.sharding import active_mesh
+        self.cfg = cfg
+        self.device = device if active_mesh() is None else None
+        self.params = params if self.device is None \
+            else jax.device_put(params, self.device)
         self.prefix_cache = prefix_cache
         self.slots, self.max_len, self.eos_id = slots, max_len, eos_id
         self.macro_steps = int(macro_steps)
@@ -770,7 +783,6 @@ class ContinuousServingEngine:
                                            None]] = None
         # the launcher thread re-enters the engine's mesh (thread-local in
         # jax); capture it at construction, like the programs' tracings
-        from repro.models.sharding import active_mesh
         self._launcher = _DecodeLauncher(active_mesh()) \
             if self.async_dispatch else None
 
@@ -783,6 +795,40 @@ class ContinuousServingEngine:
                              self._use_pallas)
 
     # ------------------------------------------------------------------
+    def _placed(self):
+        """Context under which a run creates its arrays: on the pinned
+        device, so the cache, the decode state and the admission vectors
+        never land on device 0 first."""
+        if self.device is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self.device)
+
+    def init_state(self):
+        """A run's empty KV cache and device-resident decode state
+        ``(cache, cur_tok, lengths, remaining, done)``; ``done=True``
+        marks free/frozen slots.  Created on the pinned device.  On a
+        mesh the state vectors are committed replicated (sticky), so
+        the FIRST fused dispatch already sees the input shardings every
+        later dispatch carries back — no steady-state re-shard."""
+        from repro.models.sharding import put_replicated
+        with self._placed():
+            lengths, cur_tok, remaining, done = put_replicated((
+                jnp.zeros((self.slots,), jnp.int32),
+                jnp.zeros((self.slots,), jnp.int32),
+                jnp.zeros((self.slots,), jnp.int32),
+                jnp.ones((self.slots,), bool)))
+            cache = M.init_cache(self.cfg, self.slots, self.max_len,
+                                 dtype=self.cfg.jnp_dtype)
+        return cache, cur_tok, lengths, remaining, done
+
+    def _local(self, tree):
+        """Move a prefix-cache hit's arrays (the trie is shared by every
+        engine of the task, so a hit may live on another group's device)
+        onto the pinned device."""
+        if self.device is None or tree is None:
+            return tree
+        return jax.device_put(tree, self.device)
+
     def _make_batch(self, req: ServeRequest):
         # HOST-side (numpy) batch: the jitted prefill uploads it at call
         # time anyway, and keeping it off-device lets the prefill pool's
@@ -814,9 +860,9 @@ class ContinuousServingEngine:
         hit = pc.match(req.prompt, frontend=req.frontend)
         self._account_hit(hit)
         if hit.full is not None:
-            return hit.full
+            return self._local(hit.full)
         if hit.prefix is not None:
-            batch = dict(batch, prefix=hit.prefix)
+            batch = dict(batch, prefix=self._local(hit.prefix))
         logits, cache = self.prefill(self.params, batch)
         pc.insert(req.prompt, logits, cache, frontend=req.frontend)
         pc.release(hit)
@@ -947,7 +993,6 @@ class ContinuousServingEngine:
             on_tokens: Optional[Callable[[int, int, List[int]],
                                          None]] = None
             ) -> Tuple[List[RequestOutput], ContinuousStats]:
-        cfg = self.cfg
         self._on_tokens = on_tokens
         if not requests:
             return [], ContinuousStats(0, 0, 0, 0.0, 0.0, 0.0, 0.0)
@@ -962,9 +1007,10 @@ class ContinuousServingEngine:
         self._pc_hits = self._pc_blocks = 0
         self._pc_flops_avoided = self._pc_flops_total = 0.0
         self._kv_raw = self._kv_wire = 0.0
-        if self.macro_steps > 0 and self.overlap_admission:
-            return self._run_overlapped(requests)
-        return self._run_boundary(requests)
+        with self._placed():
+            if self.macro_steps > 0 and self.overlap_admission:
+                return self._run_overlapped(requests)
+            return self._run_boundary(requests)
 
     # ------------------------------------------------------------------
     def _run_boundary(self, requests: Sequence[ServeRequest]
@@ -973,22 +1019,10 @@ class ContinuousServingEngine:
         boundary with free slots runs prefill while all live slots wait.
         Kept as the A/B baseline — token streams are identical to the
         overlapped schedule."""
-        cfg = self.cfg
         K = self.macro_steps
         pending = deque(requests)
         slot_states: List[_Slot] = [_Slot() for _ in range(self.slots)]
-        # device-resident decode state; done=True marks free/frozen slots.
-        # The initial placement is committed mesh-replicated (sticky) so
-        # the FIRST fused dispatch already sees the same input shardings
-        # every later dispatch carries back — no steady-state re-shard.
-        from repro.models.sharding import put_replicated
-        lengths, cur_tok, remaining, done = put_replicated((
-            jnp.zeros((self.slots,), jnp.int32),
-            jnp.zeros((self.slots,), jnp.int32),
-            jnp.zeros((self.slots,), jnp.int32),
-            jnp.ones((self.slots,), bool)))
-        cache = M.init_cache(cfg, self.slots, self.max_len,
-                             dtype=cfg.jnp_dtype)
+        cache, cur_tok, lengths, remaining, done = self.init_state()
         outputs: List[RequestOutput] = []
         step_no = 0
         busy_acc = 0.0
@@ -1151,9 +1185,6 @@ class ContinuousServingEngine:
         ``prefill_fallbacks`` counts the recoveries, the streams do not
         change.
         """
-        from repro.models.sharding import put_replicated
-
-        cfg = self.cfg
         K = self.macro_steps
         W = self.wave_steps
         eos = self.eos_id
@@ -1161,15 +1192,7 @@ class ContinuousServingEngine:
         pending = deque(requests)
         shadows: deque = deque()          # in-flight speculative prefills
         slot_states: List[_Slot] = [_Slot() for _ in range(self.slots)]
-        # sticky replicated placement: the first fused dispatch sees the
-        # same carried-state shardings as every later one (no re-shard)
-        lengths, cur_tok, remaining, done = put_replicated((
-            jnp.zeros((self.slots,), jnp.int32),
-            jnp.zeros((self.slots,), jnp.int32),
-            jnp.zeros((self.slots,), jnp.int32),
-            jnp.ones((self.slots,), bool)))
-        cache = M.init_cache(cfg, self.slots, self.max_len,
-                             dtype=cfg.jnp_dtype)
+        cache, cur_tok, lengths, remaining, done = self.init_state()
         outputs: List[RequestOutput] = []
         step_no = 0
         busy_acc = 0.0
@@ -1201,7 +1224,7 @@ class ContinuousServingEngine:
                     # exact full-prompt hit: no prefill anywhere and —
                     # disaggregated — no KV hop either; the assembled
                     # blocks are already hub-resident fresh copies
-                    logits, cache = hit.full
+                    logits, cache = self._local(hit.full)
                     shadows.append(_Shadow(
                         req, logits,
                         None if req.max_new <= 1 else cache))
@@ -1210,7 +1233,8 @@ class ContinuousServingEngine:
             if hit is not None and hit.prefix is not None:
                 # partial hit: prefill resumes from the cached span —
                 # local and remote dispatch alike run only the tail rows
-                batch = dict(batch, prefix=hit.prefix)
+                # (the worker moves the prefix onto its own device)
+                batch = dict(batch, prefix=self._local(hit.prefix))
             # a single-token request never touches a slot: park only its
             # logits, so speculative singles cost no cache memory
             if _use_remote():
@@ -1251,6 +1275,7 @@ class ContinuousServingEngine:
             prefix = sh.hit.prefix if sh.hit is not None else None
             try:
                 logits, blk, t_hop = worker.fetch(sh.logits, sh.cache,
+                                                  target=self.device,
                                                   prefix=prefix)
                 t_kv_transfer += t_hop
                 raw, wire = worker.last_fetch_bytes
@@ -1268,7 +1293,7 @@ class ContinuousServingEngine:
                 if prefix is not None:
                     # the hit's arrays outlive any eviction (plain
                     # references) — the local redo still resumes
-                    batch = dict(batch, prefix=prefix)
+                    batch = dict(batch, prefix=self._local(prefix))
                 logits, pre = self.prefill(self.params, batch)
                 if pc is not None:
                     pc.insert(sh.req.prompt, logits, pre,

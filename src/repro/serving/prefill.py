@@ -170,6 +170,11 @@ class PrefillWorker:
         """Launch one B=1 prefill on the prefill group (async dispatch —
         returns in-flight handles, never blocks)."""
         self._check("dispatch")
+        if self.device is not None and "prefix" in batch:
+            # a prefix-cache hit lives decode-side: resume from a copy
+            # on this group's device
+            batch = dict(batch,
+                         prefix=jax.device_put(batch["prefix"], self.device))
         out = self._prefill(self.params, batch)
         self.dispatched += 1
         return out
@@ -178,10 +183,10 @@ class PrefillWorker:
         """Transfer a finished block back to the decode group.
 
         Returns ``(logits, cache, t_kv_transfer_s)`` with both arrays on
-        ``target`` (the decode group's device; None = the default device)
-        and the transfer hop priced by the edge's LinkModel over the
-        bytes that actually cross the link.  Raises if the group died in
-        flight.
+        ``target`` (the decode group's device; None leaves them where
+        they are) and the transfer hop priced by the edge's LinkModel
+        over the bytes that actually cross the link.  Raises if the group
+        died in flight.
 
         When ``prefix`` is a prefix-cache hit's KV pytree (rows ``[0,q)``
         already resident decode-side), only the tail rows ``[q, S)`` are
@@ -211,26 +216,25 @@ class PrefillWorker:
                 packed, wire_kv = compact_kv_hop(
                     cache, q_rows, keep_rate=self.kv_keep_rate)
                 wire = _tree_bytes(logits) + wire_kv
-        tgt = target
-        if tgt is None and self.device is not None:
-            tgt = jax.devices()[0]
-        if tgt is not None and tgt != self.device:
+        if target is not None and target != self.device:
             # an actual cross-device move; co-located groups (CI hosts,
             # mesh-wide workers) skip the copy — the hop is still PRICED
             # below, exactly like the engine's simulated link latencies.
             # With a packed hop only the compacted repr crosses; the raw
             # cache stays on the prefill device and is dropped.
-            logits = jax.device_put(logits, tgt)
+            logits = jax.device_put(logits, target)
             if packed is not None:
                 packed = {
-                    name: ((jax.device_put(val[0], tgt),
-                            jax.device_put(val[1], tgt), val[2])
+                    name: ((jax.device_put(val[0], target),
+                            jax.device_put(val[1], target), val[2])
                            if isinstance(val, tuple) else val)
                     for name, val in packed.items()}
             elif cache is not None:
-                cache = jax.device_put(cache, tgt)
+                cache = jax.device_put(cache, target)
         if packed is not None:
             from repro.serving.prefix_cache import restore_kv_hop
+            if target is not None:
+                prefix = jax.device_put(prefix, target)
             cache = restore_kv_hop(packed, prefix)
         self.transferred_bytes += wire
         self.kv_bytes_raw += raw

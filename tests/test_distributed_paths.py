@@ -18,9 +18,9 @@ SCRIPT = textwrap.dedent("""
     from repro.configs.base import get_config, reduced
     from repro.models import moe as moe_mod
     from repro.models import attention as attn_mod
-    from repro.models.sharding import activation_sharding
+    from repro.models.sharding import activation_sharding, make_mesh
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     out = {}
 
     # ---- MoE: shard_map path vs global path -----------------------------
@@ -54,7 +54,7 @@ SCRIPT = textwrap.dedent("""
     # rows straddle both sequence shards.  B=4 shards the batch over
     # "data" (indices shard with it); B=3 spills "data" onto the sequence
     # dim (indices replicated) — both layouts must match the vmap
-    # reference exactly, with the cache donated through shard_map_compat.
+    # reference exactly, with the cache donated through jax.shard_map.
     vec_errs = []
     row_upd = lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
         c, n, i, axis=0)

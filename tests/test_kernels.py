@@ -73,6 +73,10 @@ def test_masked_compact_properties(seed, keep):
 @pytest.mark.parametrize("B,S,H,Hkv,dh,win", [
     (2, 512, 8, 2, 64, 0), (1, 1024, 8, 8, 128, 0),
     (2, 512, 16, 4, 64, 128), (2, 256, 4, 1, 128, 0),
+    # caches whose length is not a multiple of the 512-row block: the
+    # trailing block overhangs the cache (the launcher sizes max_len as
+    # prompt + max_new + 8)
+    (3, 600, 8, 2, 64, 0), (2, 1100, 4, 4, 128, 100),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_decode_attention_matches_ref(B, S, H, Hkv, dh, win, dtype):
