@@ -43,6 +43,7 @@ from repro.core.scheduler import (Backoff, ControllerConfig, PrefillRouter,
                                   SplitRatioController)
 from repro.serving.engine import (ContinuousServingEngine, RequestOutput,
                                   ServeRequest)
+from repro.serving.spans import span
 
 
 # ---------------------------------------------------------------------------
@@ -535,7 +536,9 @@ class HeteroRuntime:
               wave: Optional[int] = None, warm: bool = True,
               verbose: bool = False,
               on_tokens: Optional[Callable[[int, int, List[int]],
-                                           None]] = None) -> ServeResult:
+                                           None]] = None,
+              on_stamp: Optional[Callable[[int, str, float], None]] = None
+              ) -> ServeResult:
         """Drain a (possibly mixed-task) request stream through the
         topology.  Returns outputs per task + structured telemetry.
 
@@ -551,7 +554,13 @@ class HeteroRuntime:
         first token in the chunk, so a re-queued request replayed on a
         survivor (bit-identical prefix) can be deduplicated by position
         — the :class:`~repro.serving.frontend.ServingFrontend` is the
-        intended consumer.  Warmup runs never stream."""
+        intended consumer.  Warmup runs never stream.
+
+        ``on_stamp(uid, event, t)`` (optional) receives each request's
+        phase stamps on ``time.perf_counter()``: ``"group"`` as the engine
+        run of the group that serves it starts (again on a survivor, for
+        a re-queued request), and the engine's ``"admit"``
+        (:meth:`ContinuousServingEngine.run`)."""
         if not self.tasks:
             raise RuntimeError("no tasks registered — call add_task first")
         decode = self._decode
@@ -637,28 +646,29 @@ class HeteroRuntime:
             latched: Dict[int, bool] = {}
             wave_links: Dict[int, Tuple[LinkModel, float]] = {}
             link_bw = {g.name: 0.0 for g in self.topology.groups}
-            for gi in range(1, len(self.topology.groups)):
-                name = self.topology.groups[gi].name
-                tr = self.link_traces.get(gi)
-                if tr is None:
-                    link_bw[name] = float(data_rate(
-                        self.topology.links[gi], self.link_distance))
-                    continue
-                eff = tr.link_at(self.topology.links[gi], wave_idx)
-                d_m = tr.distance_at(wave_idx)
-                feasible = tr.feasible(wave_idx)
-                wave_links[gi] = (eff, d_m)
-                link_bw[name] = float(data_rate(eff, d_m))
-                if gi == self.topology.prefill_spoke:
-                    if self.prefill_router is not None:
-                        self.prefill_router.link = eff
-                        self.prefill_router.distance = d_m
-                        self.prefill_router.mobility_latched = not feasible
-                    for spec in self.tasks.values():
-                        if spec.prefill_worker is not None:
-                            spec.prefill_worker.set_link(eff, d_m)
-                else:
-                    latched[gi] = not feasible
+            with span("runtime.link", wave=wave_idx):
+                for gi in range(1, len(self.topology.groups)):
+                    name = self.topology.groups[gi].name
+                    tr = self.link_traces.get(gi)
+                    if tr is None:
+                        link_bw[name] = float(data_rate(
+                            self.topology.links[gi], self.link_distance))
+                        continue
+                    eff = tr.link_at(self.topology.links[gi], wave_idx)
+                    d_m = tr.distance_at(wave_idx)
+                    feasible = tr.feasible(wave_idx)
+                    wave_links[gi] = (eff, d_m)
+                    link_bw[name] = float(data_rate(eff, d_m))
+                    if gi == self.topology.prefill_spoke:
+                        if self.prefill_router is not None:
+                            self.prefill_router.link = eff
+                            self.prefill_router.distance = d_m
+                            self.prefill_router.mobility_latched = not feasible
+                        for spec in self.tasks.values():
+                            if spec.prefill_worker is not None:
+                                spec.prefill_worker.set_link(eff, d_m)
+                    else:
+                        latched[gi] = not feasible
             n_latched = sum(latched.values()) + (
                 1 if self.prefill_router is not None
                 and self.prefill_router.mobility_latched else 0)
@@ -684,20 +694,23 @@ class HeteroRuntime:
             # re-routes to the cold survivors.  Like the β latch, hotness
             # is advisory: an all-hot fleet still decodes (the frontend
             # sheds in that regime instead)
-            adm = self.admission.assess()
-            adm_mask = tuple(e and not a.hot
-                             for e, a in zip(eff_mask, adm))
-            wave_rerouted = 0
-            if any(adm_mask) and adm_mask != eff_mask:
-                _, counts_base = self._split_for(len(chunk), split,
+            with span("runtime.split", wave=wave_idx):
+                adm = self.admission.assess()
+                adm_mask = tuple(e and not a.hot
+                                 for e, a in zip(eff_mask, adm))
+                wave_rerouted = 0
+                if any(adm_mask) and adm_mask != eff_mask:
+                    _, counts_base = self._split_for(len(chunk), split,
+                                                     eff_mask)
+                    eff_mask = adm_mask
+                    sv, counts = self._split_for(len(chunk), split,
                                                  eff_mask)
-                eff_mask = adm_mask
-                sv, counts = self._split_for(len(chunk), split, eff_mask)
-                wave_rerouted = sum(c for c, keep
-                                    in zip(counts_base, eff_mask)
-                                    if not keep)
-            else:
-                sv, counts = self._split_for(len(chunk), split, eff_mask)
+                    wave_rerouted = sum(c for c, keep
+                                        in zip(counts_base, eff_mask)
+                                        if not keep)
+                else:
+                    sv, counts = self._split_for(len(chunk), split,
+                                                 eff_mask)
             total_rerouted += wave_rerouted
             counts = list(counts)
 
@@ -771,13 +784,20 @@ class HeteroRuntime:
                 try:
                     if share:
                         grp.health.check("dispatch", grp.name)
-                    for task, reqs_t in by_task.items():
-                        spec = self.tasks[task]
-                        outs, st = spec.engines[grp.name].run(
-                            self._capped(spec, reqs_t),
-                            on_tokens=on_tokens)
-                        staged.append((task, outs, st))
-                        payload += len(reqs_t) * spec.payload_bytes_per_item
+                    with span("runtime.group_run", group=grp.name,
+                              wave=wave_idx, n=len(share)):
+                        for task, reqs_t in by_task.items():
+                            spec = self.tasks[task]
+                            if on_stamp is not None:
+                                t_run = time.perf_counter()
+                                for req in reqs_t:
+                                    on_stamp(req.uid, "group", t_run)
+                            outs, st = spec.engines[grp.name].run(
+                                self._capped(spec, reqs_t),
+                                on_tokens=on_tokens, on_stamp=on_stamp)
+                            staged.append((task, outs, st))
+                            payload += (len(reqs_t)
+                                        * spec.payload_bytes_per_item)
                     if share:
                         grp.health.check("await", grp.name)
                 except GroupUnavailableError:
@@ -816,8 +836,9 @@ class HeteroRuntime:
                 if gi > 0 and share and not failed:
                     eff_link, eff_dist = wave_links.get(
                         gi, (self.topology.links[gi], self.link_distance))
-                    t_link[d] = float(offload_latency(
-                        eff_link, payload, eff_dist))
+                    with span("runtime.link", wave=wave_idx):
+                        t_link[d] = float(offload_latency(
+                            eff_link, payload, eff_dist))
                 per_group[grp.name] = {
                     "n": 0 if failed else len(share), "wall_s": t_group[d],
                     "link_s": t_link[d], "tokens": toks_group[d],
@@ -926,31 +947,34 @@ class HeteroRuntime:
                 admission_rerouted=wave_rerouted,
                 power_headroom_w=tuple(a.power_headroom_w for a in adm),
                 mem_headroom_frac=tuple(a.mem_headroom_frac for a in adm))
-            if split is None and self.controller is not None:
-                self.controller.observe(rep)
-            if self.prefill_router is not None:
-                # feed the router the wave's live prices.  The engines'
-                # t_prefill_overlap_s wall covers exactly the TOP-UP
-                # shadow dispatches (shadow_prefills), local and remote
-                # alike — so both rates divide that wall by the top-up
-                # count; inline boundary dispatches are excluded from
-                # both sides.  KV hops are per TRANSFERRED block
-                # (prefill_offloaded, inline offloads included).
-                n_off = sum(offloaded_group)
-                n_topup = sum(shadow_group)
-                wave_ftotal = sum(ftotal_group)
-                self.prefill_router.observe(
-                    local_s=sum(overlap_s_group) if n_off == 0 else 0.0,
-                    n_local=n_topup if n_off == 0 else 0,
-                    remote_s=sum(overlap_s_group) if n_off else 0.0,
-                    n_remote=n_topup if n_off else 0,
-                    transfer_s=sum(kv_s_group), n_transfers=n_off,
-                    # price hops on WIRE bytes — what the link carried —
-                    # and the residual prefill fraction the cache left
-                    payload_bytes=sum(kv_wire_group),
-                    prefix_residual=(1.0 - sum(favoid_group) / wave_ftotal)
-                    if wave_ftotal > 0 else None,
-                    fallbacks=sum(fallback_group))
+            with span("runtime.split", wave=wave_idx):
+                if split is None and self.controller is not None:
+                    self.controller.observe(rep)
+                if self.prefill_router is not None:
+                    # feed the router the wave's live prices.  The engines'
+                    # t_prefill_overlap_s wall covers exactly the TOP-UP
+                    # shadow dispatches (shadow_prefills), local and remote
+                    # alike — so both rates divide that wall by the top-up
+                    # count; inline boundary dispatches are excluded from
+                    # both sides.  KV hops are per TRANSFERRED block
+                    # (prefill_offloaded, inline offloads included).
+                    n_off = sum(offloaded_group)
+                    n_topup = sum(shadow_group)
+                    wave_ftotal = sum(ftotal_group)
+                    self.prefill_router.observe(
+                        local_s=sum(overlap_s_group) if n_off == 0 else 0.0,
+                        n_local=n_topup if n_off == 0 else 0,
+                        remote_s=sum(overlap_s_group) if n_off else 0.0,
+                        n_remote=n_topup if n_off else 0,
+                        transfer_s=sum(kv_s_group), n_transfers=n_off,
+                        # price hops on WIRE bytes — what the link
+                        # carried — and the residual prefill fraction the
+                        # cache left
+                        payload_bytes=sum(kv_wire_group),
+                        prefix_residual=(1.0 - sum(favoid_group)
+                                         / wave_ftotal)
+                        if wave_ftotal > 0 else None,
+                        fallbacks=sum(fallback_group))
             waves_tel.append({
                 "wave": len(waves_tel), "n": len(chunk),
                 "split": [round(float(f), 4) for f in sv.fractions],

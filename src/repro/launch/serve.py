@@ -200,7 +200,8 @@ def serve_continuous(cfg, params, reqs, *, prompt_len: int, max_new: int,
         # admission waves, streamed tokens, power/memory shedding
         import asyncio
 
-        from repro.serving.frontend import FrontendError, ServingFrontend
+        from repro.serving.frontend import (PHASES, FrontendError,
+                                           ServingFrontend)
         tenants = tenants or parse_tenants("interactive:0:2:0.5,batch:1:1")
         fe = ServingFrontend(runtime, tenants, queue_depth=queue_depth,
                              shed_depth=shed_depth,
@@ -236,7 +237,9 @@ def serve_continuous(cfg, params, reqs, *, prompt_len: int, max_new: int,
                   f"ttft p50/p99={ts['ttft_p50_s'] * 1e3:.1f}/"
                   f"{ts['ttft_p99_s'] * 1e3:.1f}ms "
                   f"itl p50/p99={ts['itl_p50_s'] * 1e3:.2f}/"
-                  f"{ts['itl_p99_s'] * 1e3:.2f}ms")
+                  f"{ts['itl_p99_s'] * 1e3:.2f}ms; ttft mean by phase "
+                  + " ".join(f"{p}={ts[p + '_mean_s'] * 1e3:.1f}"
+                             for p in PHASES) + "ms")
         if telemetry_path:
             import json as _json
             with open(telemetry_path, "w") as fh:

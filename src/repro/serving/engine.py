@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import model as M
+from repro.serving.spans import span
 
 
 def resolve_use_pallas(use_pallas: Union[bool, str]) -> bool:
@@ -517,6 +518,15 @@ def admit_boundary(cfg, big_cache, blocks, slot_ids, cur_tok, lengths,
     return cache, cur_tok, lengths, remaining, done, first
 
 
+def _cfg_program(fn, cfg, name: str):
+    """``fn`` with ``cfg`` bound, named so that its program traces as
+    ``jit_<name>`` (a bare partial or lambda traces as ``jit__unknown``
+    and cannot be told apart in a device trace)."""
+    bound = functools.partial(fn, cfg)
+    bound.__name__ = name
+    return bound
+
+
 @dataclass
 class ServeRequest:
     """One unit of work for the continuous-batching queue."""
@@ -754,7 +764,7 @@ class ContinuousServingEngine:
                 make_serve_step(cfg, use_pallas=self._use_pallas),
                 donate_argnums=(1,))
             self._write_slot = jax.jit(
-                lambda big, pre, slot: write_slot_cache(cfg, big, pre, slot),
+                _cfg_program(write_slot_cache, cfg, "write_slot"),
                 donate_argnums=(0,))
             # fused cross-group splice: takes the LIST of M block trees so
             # the stack traces into the same program as the scatter (one
@@ -764,14 +774,13 @@ class ContinuousServingEngine:
             # fault tier instead hard-deletes them after the call to
             # enforce the consumed-after-splice invariant
             self._splice_slots = jax.jit(
-                lambda big, blocks, ids: splice_slot_caches(cfg, big,
-                                                            blocks, ids),
+                _cfg_program(splice_slot_caches, cfg, "splice_slots"),
                 donate_argnums=(0,))
             # fused boundary: cache splice + state scatter in ONE donated
             # program (big cache + all four state vectors); the blocks
             # are consumed-by-contract exactly like _splice_slots'
             self._admit_boundary = jax.jit(
-                functools.partial(admit_boundary, cfg),
+                _cfg_program(admit_boundary, cfg, "admit_boundary"),
                 static_argnames=("eos_id",),
                 donate_argnums=(0, 3, 4, 5, 6))
             self._loops: Dict[Tuple[int, Optional[int]], Any] = {}
@@ -781,6 +790,8 @@ class ContinuousServingEngine:
         # the ingress frontend listens; None = batch mode, no streaming)
         self._on_tokens: Optional[Callable[[int, int, List[int]],
                                            None]] = None
+        # per-request phase stamps of the CURRENT run (set per run())
+        self._on_stamp: Optional[Callable[[int, str, float], None]] = None
         # the launcher thread re-enters the engine's mesh (thread-local in
         # jax); capture it at construction, like the programs' tracings
         self._launcher = _DecodeLauncher(active_mesh()) \
@@ -877,6 +888,16 @@ class ContinuousServingEngine:
         position always carries the same token."""
         if self._on_tokens is not None and len(toks):
             self._on_tokens(uid, start, [int(t) for t in toks])
+
+    def _stamp(self, event: str, uids) -> None:
+        """Report ``event`` at this instant (``perf_counter``) for every
+        request in ``uids`` to the run's ``on_stamp`` hook.  The engine
+        stamps ``"admit"``: the boundary that splices a request into a
+        slot (or flushes a single-token one) has been dispatched."""
+        if self._on_stamp is not None:
+            t = time.perf_counter()
+            for uid in uids:
+                self._on_stamp(uid, event, t)
 
     def _consume_block(self, block, slot_states, K: int,
                        step_no: int) -> Tuple[int, float]:
@@ -979,6 +1000,7 @@ class ContinuousServingEngine:
                     cur_tok, lengths, remaining, done, ids, logits, plens,
                     mnews,
                     eos_id=-1 if self.eos_id is None else int(self.eos_id))
+            self._stamp("admit", [req.uid for _, req, _ in admitted])
             firsts = np.asarray(first_dev)
             syncs = 1
             for (slot, req, _), first in zip(admitted, firsts):
@@ -991,9 +1013,14 @@ class ContinuousServingEngine:
     # ------------------------------------------------------------------
     def run(self, requests: Sequence[ServeRequest],
             on_tokens: Optional[Callable[[int, int, List[int]],
-                                         None]] = None
+                                         None]] = None,
+            on_stamp: Optional[Callable[[int, str, float], None]] = None
             ) -> Tuple[List[RequestOutput], ContinuousStats]:
+        """Serve ``requests`` to completion.  ``on_tokens(uid, start,
+        tokens)`` streams host-landed tokens; ``on_stamp(uid, event, t)``
+        receives each request's phase stamps (:meth:`_stamp`)."""
         self._on_tokens = on_tokens
+        self._on_stamp = on_stamp
         if not requests:
             return [], ContinuousStats(0, 0, 0, 0.0, 0.0, 0.0, 0.0)
         P = len(requests[0].prompt)
@@ -1212,9 +1239,13 @@ class ContinuousServingEngine:
             return (worker is not None and self.prefill_remote
                     and worker.healthy)
 
-        def _dispatch_shadow():
-            nonlocal n_offloaded, n_fallbacks
+        def _dispatch_shadow(inline: int):
             req = pending.popleft()
+            with span("engine.prefill", uid=req.uid, inline=inline):
+                _shadow_prefill(req)
+
+        def _shadow_prefill(req: ServeRequest):
+            nonlocal n_offloaded, n_fallbacks
             pc = self.prefix_cache
             hit = None
             if pc is not None:
@@ -1294,7 +1325,8 @@ class ContinuousServingEngine:
                     # the hit's arrays outlive any eviction (plain
                     # references) — the local redo still resumes
                     batch = dict(batch, prefix=self._local(prefix))
-                logits, pre = self.prefill(self.params, batch)
+                with span("engine.prefill", uid=sh.req.uid, inline=1):
+                    logits, pre = self.prefill(self.params, batch)
                 if pc is not None:
                     pc.insert(sh.req.prompt, logits, pre,
                               frontend=sh.req.frontend)
@@ -1309,7 +1341,7 @@ class ContinuousServingEngine:
             # --- 1. splice shadows into free slots (macro boundary) ----
             t0 = time.perf_counter()
             boundary_step = step_no
-            live_before = any(s.busy for s in slot_states)
+            n_live = sum(1 for s in slot_states if s.busy)
             inline = 0
             newly: List[Tuple[int, ServeRequest, Any]] = []
             blocks: List[Any] = []
@@ -1329,7 +1361,7 @@ class ContinuousServingEngine:
                 if not shadows:
                     if not pending:
                         break
-                    _dispatch_shadow()   # shadow miss: prefill exposed
+                    _dispatch_shadow(1)  # shadow miss: prefill exposed
                     inline += 1
                 sh = shadows.popleft()
                 if sh.req.max_new <= 1:
@@ -1353,40 +1385,45 @@ class ContinuousServingEngine:
                     inline += exposed
                     flushed.append(sh)
                 singles = flushed
-            if inline and live_before:
-                stalls += 1     # decode waited on an un-overlapped prefill
-            single_dev = None
-            if singles:
-                single_dev = jnp.argmax(jnp.concatenate(
-                    [sh.logits for sh in singles], axis=0),
-                    axis=-1).astype(jnp.int32)
-            first_dev = None
-            if newly:
-                # ONE fused donated boundary dispatch for all admitted
-                # blocks (KV transfers and local shadows alike): cache
-                # splice + decode-state scatter in a single program over
-                # FIXED-WIDTH padded vectors/blocks, so every boundary
-                # reuses one compiled program and one input sharding
-                # regardless of the admitted count.  The wall lands in
-                # the arm's bucket: splice (disaggregated) vs slot-write
-                # (local-shadow baseline) — never both.
-                tb0 = time.perf_counter()
-                ids, logits_cat, plens, mnews = self._pad_admit_args(newly)
-                blks = tuple(blocks
-                             + [blocks[-1]] * (self.slots - len(blocks)))
-                cache, cur_tok, lengths, remaining, done, first_dev = \
-                    self._admit_boundary(
-                        cache, blks, ids, cur_tok, lengths, remaining,
-                        done, logits_cat, plens, mnews,
-                        eos_id=-1 if eos is None else int(eos))
-                if worker is not None:
-                    t_splice += time.perf_counter() - tb0
-                else:
-                    t_slot_write += time.perf_counter() - tb0
-                for slot, req, _ in newly:
-                    slot_states[slot] = _Slot(
-                        uid=req.uid, remaining=req.max_new - 1,
-                        tokens=[], admitted_step=step_no)
+            stalled = int(inline > 0 and n_live > 0)
+            stalls += stalled   # decode waited on an un-overlapped prefill
+            single_dev = first_dev = None
+            with span("engine.boundary", admitted=len(newly), live=n_live,
+                      stall=stalled):
+                if singles:
+                    single_dev = jnp.argmax(jnp.concatenate(
+                        [sh.logits for sh in singles], axis=0),
+                        axis=-1).astype(jnp.int32)
+                    self._stamp("admit", [sh.req.uid for sh in singles])
+                if newly:
+                    # ONE fused donated boundary dispatch for all admitted
+                    # blocks (KV transfers and local shadows alike): cache
+                    # splice + decode-state scatter in a single program
+                    # over FIXED-WIDTH padded vectors/blocks, so every
+                    # boundary reuses one compiled program and one input
+                    # sharding regardless of the admitted count.  The
+                    # wall lands in the arm's bucket: splice
+                    # (disaggregated) vs slot-write (local-shadow
+                    # baseline) — never both.
+                    tb0 = time.perf_counter()
+                    ids, logits_cat, plens, mnews = \
+                        self._pad_admit_args(newly)
+                    blks = tuple(blocks + [blocks[-1]]
+                                 * (self.slots - len(blocks)))
+                    cache, cur_tok, lengths, remaining, done, first_dev = \
+                        self._admit_boundary(
+                            cache, blks, ids, cur_tok, lengths, remaining,
+                            done, logits_cat, plens, mnews,
+                            eos_id=-1 if eos is None else int(eos))
+                    if worker is not None:
+                        t_splice += time.perf_counter() - tb0
+                    else:
+                        t_slot_write += time.perf_counter() - tb0
+                    self._stamp("admit", [req.uid for _, req, _ in newly])
+                    for slot, req, _ in newly:
+                        slot_states[slot] = _Slot(
+                            uid=req.uid, remaining=req.max_new - 1,
+                            tokens=[], admitted_step=step_no)
             t_prefill += time.perf_counter() - t0
 
             # --- 2. launch the macro-step (never waits on prefill) -----
@@ -1399,16 +1436,19 @@ class ContinuousServingEngine:
             # step 4 (step 3 only dispatches fresh prefills).
             t0 = time.perf_counter()
             launch = None
-            if any(s.busy and s.remaining > 0 and not _eos_done(s)
-                   for s in slot_states):
-                fn = self._get_wave(K, W) if W > 1 else self._get_loop(K)
-                if self._launcher is not None:
-                    launch = self._launcher.submit(
-                        fn, self.params, cache, cur_tok, lengths,
-                        remaining, done)
-                else:
-                    launch = fn(self.params, cache, cur_tok, lengths,
-                                remaining, done)
+            n_decoding = sum(1 for s in slot_states if s.busy
+                             and s.remaining > 0 and not _eos_done(s))
+            if n_decoding:
+                with span("engine.launch", live=n_decoding):
+                    fn = self._get_wave(K, W) if W > 1 \
+                        else self._get_loop(K)
+                    if self._launcher is not None:
+                        launch = self._launcher.submit(
+                            fn, self.params, cache, cur_tok, lengths,
+                            remaining, done)
+                    else:
+                        launch = fn(self.params, cache, cur_tok, lengths,
+                                    remaining, done)
             t_dispatch += time.perf_counter() - t0
 
             # --- 3. top up speculative shadow prefills -----------------
@@ -1419,41 +1459,43 @@ class ContinuousServingEngine:
             # back on the critical path.  At most `slots` B=1 prefill
             # caches are parked; parked singles hold logits only.
             t0o = time.perf_counter()
-            while pending and sum(1 for sh in shadows
-                                  if sh.req.max_new > 1) < self.slots:
-                _dispatch_shadow()
-                n_shadow += 1
+            with span("engine.topup", shadows=len(shadows)):
+                while pending and sum(1 for sh in shadows
+                                      if sh.req.max_new > 1) < self.slots:
+                    _dispatch_shadow(0)
+                    n_shadow += 1
             dt_overlap = time.perf_counter() - t0o
             t_overlap += dt_overlap
 
             # --- 4. the ONE await: token block + piggybacked firsts ----
             t0a = time.perf_counter()
-            block = None
-            if launch is not None:
-                res = launch.result() if hasattr(launch, "result") \
-                    else launch
-                toks, cache, cur_tok, lengths, remaining, done = res
-                block = np.asarray(toks)
-                if block.ndim == 3:       # wave driver: [W, K, slots]
-                    block = block.reshape(-1, self.slots)
-                host_syncs += 1
-                dispatches += W
-                wave_launches += 1
-            if first_dev is not None:
-                firsts = np.asarray(first_dev)   # enqueued before the
-                host_syncs += 1                  # loop: instant by now
-                for (slot, req, _), first in zip(newly, firsts):
-                    slot_states[slot].tokens.append(int(first))
-                    self._emit_tokens(req.uid, 0, [int(first)])
-            if single_dev is not None:
-                host_syncs += 1
-                for sh, first in zip(singles, np.asarray(single_dev)):
-                    outputs.append(RequestOutput(
-                        uid=sh.req.uid,
-                        tokens=np.asarray([int(first)], np.int32),
-                        admitted_step=boundary_step,
-                        finished_step=boundary_step))
-                    self._emit_tokens(sh.req.uid, 0, [int(first)])
+            with span("engine.await"):
+                block = None
+                if launch is not None:
+                    res = launch.result() if hasattr(launch, "result") \
+                        else launch
+                    toks, cache, cur_tok, lengths, remaining, done = res
+                    block = np.asarray(toks)
+                    if block.ndim == 3:       # wave driver: [W, K, slots]
+                        block = block.reshape(-1, self.slots)
+                    host_syncs += 1
+                    dispatches += W
+                    wave_launches += 1
+                if first_dev is not None:
+                    firsts = np.asarray(first_dev)   # enqueued before the
+                    host_syncs += 1                  # loop: instant by now
+                    for (slot, req, _), first in zip(newly, firsts):
+                        slot_states[slot].tokens.append(int(first))
+                        self._emit_tokens(req.uid, 0, [int(first)])
+                if single_dev is not None:
+                    host_syncs += 1
+                    for sh, first in zip(singles, np.asarray(single_dev)):
+                        outputs.append(RequestOutput(
+                            uid=sh.req.uid,
+                            tokens=np.asarray([int(first)], np.int32),
+                            admitted_step=boundary_step,
+                            finished_step=boundary_step))
+                        self._emit_tokens(sh.req.uid, 0, [int(first)])
             t_await += time.perf_counter() - t0a
 
             if block is not None:

@@ -23,7 +23,10 @@ Everything before this PR entered through benchmarks wave-draining
 The scheduler loop feeds the continuous engines at wave boundaries:
 each iteration selects one wave of requests and runs ``runtime.serve``
 for it in a worker thread, streaming tokens back through the event
-loop.  Chaos contract (tested in tests/test_frontend.py): every
+loop.  Each request's time to first token is split into four phases
+stamped where the work happens (:data:`PHASES`), and the loop, the
+runtime and the engines open named host spans (``serving/spans.py``).
+Chaos contract (tested in tests/test_frontend.py): every
 ACCEPTED request either completes bit-identically on surviving groups
 — replays after a mid-wave group kill are deduplicated by stream
 position, which bit-identity makes sound — or, when every decode group
@@ -42,6 +45,13 @@ import numpy as np
 from repro.core.offload import GroupUnavailableError
 from repro.core.scheduler import TenantClass, TenantScheduler
 from repro.serving.engine import RequestOutput, ServeRequest
+from repro.serving.spans import span
+
+# a request's time to first token, in four phases that end where the next
+# begins: submit -> picked into a wave (frontend) -> its group's engine run
+# starts (runtime) -> the boundary that admits it is dispatched (engine) ->
+# its first token is pushed on the event loop
+PHASES = ("queue", "group_wait", "admit_wait", "first_token")
 
 
 class FrontendError(RuntimeError):
@@ -74,6 +84,9 @@ class _Entry:
     stream: "TokenStream"
     t_submit: float
     streamed: int = 0            # tokens already pushed (dedupe position)
+    t_pick: float = -1.0         # picked into a serve wave
+    t_group: float = -1.0        # its group's engine run started
+    t_admit: float = -1.0        # its admission boundary was dispatched
     t_first: float = -1.0
     t_last: float = -1.0
 
@@ -84,7 +97,9 @@ class TokenStream:
     ``async for tok in stream`` yields ints as they land; ``collect()``
     drains to the final np.int32 array.  A typed refusal/abort raises
     out of the iterator.  TTFT/ITL are stamped by the frontend at
-    arrival time and exposed on the stream after completion."""
+    arrival time and exposed on the stream after completion, with
+    ``phases``: TTFT split into :data:`PHASES` (seconds, summing to
+    ``ttft_s``), None until the first token lands."""
 
     def __init__(self, uid: int, tenant: str,
                  loop: asyncio.AbstractEventLoop):
@@ -97,6 +112,7 @@ class TokenStream:
         self.done = False
         self.ttft_s: float = -1.0
         self.itl_s: List[float] = []   # per-token inter-arrival samples
+        self.phases: Optional[Dict[str, float]] = None
 
     # -- producer side (event-loop thread only) -----------------------
     def _push(self, toks: List[int]) -> None:
@@ -139,6 +155,8 @@ class TenantStats:
     max_queue_depth: int = 0
     ttft_s: List[float] = field(default_factory=list)
     itl_s: List[float] = field(default_factory=list)
+    phase_s: Dict[str, List[float]] = field(
+        default_factory=lambda: {p: [] for p in PHASES})
 
 
 def _pctl(xs: Sequence[float], q: float) -> float:
@@ -268,6 +286,22 @@ class ServingFrontend:
         self._loop.call_soon_threadsafe(self._push_tokens, uid, start,
                                         toks)
 
+    def _on_stamp(self, uid: int, event: str, t: float) -> None:
+        """Runtime/engine phase hook — called on the serve WORKER thread;
+        queued behind the tokens already sent, so a stamp always lands
+        before the first token it leads to.  A re-queued request is
+        stamped again by its next attempt."""
+        self._loop.call_soon_threadsafe(self._set_stamp, uid, event, t)
+
+    def _set_stamp(self, uid: int, event: str, t: float) -> None:
+        entry = self._live.get(uid)
+        if entry is None:
+            return
+        if event == "group":
+            entry.t_group = t
+        elif event == "admit":
+            entry.t_admit = t
+
     def _push_tokens(self, uid: int, start: int, toks: List[int]) -> None:
         entry = self._live.get(uid)
         if entry is None or entry.stream.done:
@@ -284,6 +318,7 @@ class ServingFrontend:
             entry.t_first = now
             entry.stream.ttft_s = now - entry.t_submit
             self.stats[entry.tenant].ttft_s.append(entry.stream.ttft_s)
+            self._close_phases(entry)
             if len(fresh) > 1:
                 gap = 0.0   # same-arrival tokens: zero inter-token gap
                 entry.stream.itl_s.extend([gap] * (len(fresh) - 1))
@@ -296,6 +331,19 @@ class ServingFrontend:
         entry.t_last = now
         entry.streamed += len(fresh)
         entry.stream._push(fresh)
+
+    def _close_phases(self, entry: _Entry) -> None:
+        """At the first token: split its TTFT into :data:`PHASES`, from
+        the stamps of the attempt that delivered it (a replay after a
+        group death streams no new first token)."""
+        marks = (entry.t_submit, entry.t_pick, entry.t_group,
+                 entry.t_admit, entry.t_first)
+        if min(marks) < 0:
+            return          # a stamp never came (a runtime without hooks)
+        phases = {p: b - a for p, a, b in zip(PHASES, marks, marks[1:])}
+        entry.stream.phases = phases
+        for p, v in phases.items():
+            self.stats[entry.tenant].phase_s[p].append(v)
 
     def _finish_entry(self, entry: _Entry, out: RequestOutput) -> None:
         tail = [int(t) for t in out.tokens[entry.streamed:]]
@@ -320,14 +368,18 @@ class ServingFrontend:
                     break
                 await self._wake.wait()
                 continue
-            picked = self.sched.select(self.wave_requests)
-            entries = [e for _, e in picked]
-            reqs = [e.request for e in entries]
+            wave = self.waves_served
+            n = min(self.wave_requests, self.sched.backlog())
+            with span("frontend.pick", wave=wave, n=n):
+                picked = self.sched.select(self.wave_requests)
+                entries = [e for _, e in picked]
+                reqs = [e.request for e in entries]
+                t_pick = time.perf_counter()
+                for e in entries:
+                    e.t_pick = t_pick
             try:
                 result = await loop.run_in_executor(
-                    None, lambda: self.runtime.serve(
-                        reqs, split=self.split, wave=len(reqs),
-                        warm=False, on_tokens=self._on_tokens))
+                    None, self._serve_wave, reqs, wave)
             except GroupUnavailableError as e:
                 # every decode group is dead: typed abort for the whole
                 # wave (requests with a live stream get the same error —
@@ -336,23 +388,33 @@ class ServingFrontend:
                     self._abort_entry(entry, f"fleet unavailable: {e}")
                 continue
             self.waves_served += 1
-            tot = result.telemetry["totals"]
-            for k in self.runtime_totals:
-                self.runtime_totals[k] += int(tot.get(k, 0))
-            by_uid = {o.uid: (task, o)
-                      for task, outs in result.outputs.items()
-                      for o in outs}
-            for entry in entries:
-                hit = by_uid.get(entry.uid)
-                if hit is None:      # defensive: serve dropped a request
-                    self._abort_entry(entry, "request lost in serve wave")
-                    continue
-                self._finish_entry(entry, hit[1])
+            with span("frontend.finish", wave=wave, n=len(entries)):
+                tot = result.telemetry["totals"]
+                for k in self.runtime_totals:
+                    self.runtime_totals[k] += int(tot.get(k, 0))
+                by_uid = {o.uid: (task, o)
+                          for task, outs in result.outputs.items()
+                          for o in outs}
+                for entry in entries:
+                    hit = by_uid.get(entry.uid)
+                    if hit is None:  # defensive: serve dropped a request
+                        self._abort_entry(entry,
+                                          "request lost in serve wave")
+                        continue
+                    self._finish_entry(entry, hit[1])
+
+    def _serve_wave(self, reqs: List[ServeRequest], wave: int):
+        """One wave through ``runtime.serve`` (on the worker thread)."""
+        with span("frontend.wave", wave=wave, n=len(reqs)):
+            return self.runtime.serve(
+                reqs, split=self.split, wave=len(reqs), warm=False,
+                on_tokens=self._on_tokens, on_stamp=self._on_stamp)
 
     # -- telemetry ----------------------------------------------------
     def telemetry(self) -> dict:
-        """Per-tenant SLO telemetry: TTFT/ITL percentiles (seconds),
-        queue/shed/abort counters.  Shape-stable for the golden schema:
+        """Per-tenant SLO telemetry: TTFT/ITL percentiles (seconds), the
+        mean of each TTFT phase (``<phase>_mean_s``), queue/shed/abort
+        counters.  Shape-stable for the golden schema:
         every field exists for every tenant from construction."""
         per_tenant = {}
         for name in sorted(self.tenants):
@@ -371,6 +433,9 @@ class ServingFrontend:
                 "itl_p50_s": _pctl(st.itl_s, 50.0),
                 "itl_p99_s": _pctl(st.itl_s, 99.0),
             }
+            for p, xs in st.phase_s.items():
+                per_tenant[name][f"{p}_mean_s"] = \
+                    float(np.mean(xs)) if xs else 0.0
         return {"queue_depth": self.queue_depth,
                 "shed_depth": self.shed_depth,
                 "wave_requests": self.wave_requests,
