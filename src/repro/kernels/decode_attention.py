@@ -2,20 +2,23 @@
 
 The decode hot-spot is memory-bound: every step streams the whole (or the
 windowed part of the) KV cache from HBM once.  The grid is
-``(B, S/s_block)``: each cell DMAs one ``[s_block, Hkv, dh]`` slab of the
-``[B, S, Hkv, dh]`` cache — ALL kv heads, so the block's two minor dims
-are the array's own ``(Hkv, dh)`` and meet Mosaic's (8, 128) tiling rule
-for any head geometry — and runs an online-softmax accumulation per kv
-head over it, keeping the ``[Hkv, G, dh]`` accumulator in VMEM scratch
-(G = query heads per kv head).  The MXU sees ``[G,dh]x[dh,Sb]`` and
+``(B, S/s_block)``: each cell DMAs one ``[s_block, Hkv, dh]`` slab of
+layer ``layer`` of the layer-stacked ``[L, B, S, Hkv, dh]`` cache, read
+in place (a single layer's ``[B, S, Hkv, dh]`` cache is a stack of one)
+— ALL kv heads, so the block's two minor dims are the array's own
+``(Hkv, dh)`` and meet Mosaic's (8, 128) tiling rule for any head
+geometry — and runs an online-softmax accumulation per kv head over it,
+keeping the ``[Hkv, G, dh]`` accumulator in VMEM scratch (G = query
+heads per kv head).  The MXU sees ``[G,dh]x[dh,Sb]`` and
 ``[G,Sb]x[Sb,dh]`` GEMMs per head.
 
-The per-sequence valid lengths ``cache_len [B]`` are scalar-prefetched
-into SMEM (the index maps receive them too, so a later clamp of the KV
-block index to the live length needs no new operand).  ``S`` need not be
-a multiple of ``s_block``: the trailing partial block's out-of-range rows
-are masked out of both the scores and the values.  ``window > 0`` adds
-the sliding-window mask (mixtral / zamba long-context).
+The per-sequence valid lengths ``cache_len [B]`` and the layer index are
+scalar-prefetched into SMEM (the index maps receive them too, so a later
+clamp of the KV block index to the live length needs no new operand).
+``S`` need not be a multiple of ``s_block``: the trailing partial
+block's out-of-range rows are masked out of both the scores and the
+values.  ``window > 0`` adds the sliding-window mask (mixtral / zamba
+long-context).
 """
 from __future__ import annotations
 
@@ -41,7 +44,7 @@ def auto_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+def _kernel(len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
             *, s_block: int, n_s: int, seq_len: int, window: int,
             scale: float):
     b, s = pl.program_id(0), pl.program_id(1)
@@ -102,36 +105,44 @@ def seq_block(S: int, Hkv: int, dh: int, itemsize: int,
     return S if S <= blk else blk
 
 
-def decode_attention_pallas(q, k_cache, v_cache, cache_len, *,
+def decode_attention_pallas(q, k_cache, v_cache, cache_len, layer=None, *,
                             window: int = 0, s_block: int = 512,
                             interpret: Optional[bool] = None):
-    """q: [B,1,H,dh]; caches: [B,S,Hkv,dh]; cache_len: [B] or scalar.
-    Returns [B,1,H,dh] (v dtype).  Matches ref.decode_attention_ref.
+    """q: [B,1,H,dh]; caches: [B,S,Hkv,dh], or the layer-stacked
+    [L,B,S,Hkv,dh] with ``layer`` (a traced scalar) selecting the layer
+    the kernel reads in place; cache_len: [B] or scalar.
+    Returns [B,1,H,dh] (v dtype).  Matches ref.decode_attention_ref
+    (of ``cache[layer]``).
     ``interpret=None`` auto-detects: compiled on TPU, interpreted off it."""
     if interpret is None:
         interpret = auto_interpret()
     if s_block % 16:
         raise ValueError(f"s_block must be a multiple of 16, got {s_block}")
+    if layer is None:
+        # one layer's cache is a stack of one: the leading axis is free
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
     B, _, H, dh = q.shape
-    S, Hkv = k_cache.shape[1], k_cache.shape[2]
+    S, Hkv = k_cache.shape[2], k_cache.shape[3]
     G = H // Hkv
     blk = seq_block(S, Hkv, dh, k_cache.dtype.itemsize, s_block)
     n_s = pl.cdiv(S, blk)
     cl = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (B,))
+    lyr = jnp.asarray(layer, jnp.int32).reshape((1,))
     qh = q.reshape(B, Hkv, G, dh)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, n_s),
         in_specs=[
-            pl.BlockSpec((None, Hkv, G, dh), lambda b, s, lens: (b, 0, 0, 0)),
-            pl.BlockSpec((None, blk, Hkv, dh),
-                         lambda b, s, lens: (b, s, 0, 0)),
-            pl.BlockSpec((None, blk, Hkv, dh),
-                         lambda b, s, lens: (b, s, 0, 0)),
+            pl.BlockSpec((None, Hkv, G, dh),
+                         lambda b, s, lens, lyr: (b, 0, 0, 0)),
+            pl.BlockSpec((None, None, blk, Hkv, dh),
+                         lambda b, s, lens, lyr: (lyr[0], b, s, 0, 0)),
+            pl.BlockSpec((None, None, blk, Hkv, dh),
+                         lambda b, s, lens, lyr: (lyr[0], b, s, 0, 0)),
         ],
         out_specs=pl.BlockSpec((None, Hkv, G, dh),
-                               lambda b, s, lens: (b, 0, 0, 0)),
+                               lambda b, s, lens, lyr: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((Hkv, G, 1), jnp.float32),
             pltpu.VMEM((Hkv, G, 1), jnp.float32),
@@ -146,5 +157,5 @@ def decode_attention_pallas(q, k_cache, v_cache, cache_len, *,
             dimension_semantics=("parallel", "arbitrary")),
         name="decode_attention",
         interpret=interpret,
-    )(cl, qh, k_cache, v_cache)
+    )(cl, lyr, qh, k_cache, v_cache)
     return out.reshape(B, 1, H, dh)

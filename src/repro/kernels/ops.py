@@ -37,9 +37,10 @@ def masked_compact(tokens, mask, capacity: int):
 
 
 @functools.partial(jax.jit, static_argnames=("window",))
-def decode_attention(q, k_cache, v_cache, cache_len, *, window: int = 0):
+def decode_attention(q, k_cache, v_cache, cache_len, layer=None, *,
+                     window: int = 0):
     from repro.kernels.decode_attention import decode_attention_pallas
-    return decode_attention_pallas(q, k_cache, v_cache, cache_len,
+    return decode_attention_pallas(q, k_cache, v_cache, cache_len, layer,
                                    window=window,
                                    interpret=auto_interpret())
 

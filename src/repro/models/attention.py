@@ -184,8 +184,32 @@ def _row_update(c, n, i):
     return jax.lax.dynamic_update_slice_in_dim(c, n.astype(c.dtype), i, axis=0)
 
 
+def _stacked_row_update(cache, new, layer, index):
+    """Write one token's rows into layer ``layer`` of the layer-stacked
+    cache [L,B,S,Hkv,dh] in place: slot b's row lands at sequence index
+    ``index[b]`` (a scalar index serves every slot).  One scatter with the
+    slot axis as a batching dim, the stacked twin of ``_row_update``'s
+    vmap; indices clamp like ``dynamic_update_slice``'s."""
+    B = cache.shape[1]
+    pos = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (B,))
+    idx = jnp.stack([jnp.broadcast_to(jnp.asarray(layer, jnp.int32), (B,)),
+                     pos], axis=1)                              # [B, 2]
+    dnums = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1, 2), inserted_window_dims=(0, 2),
+        scatter_dims_to_operand_dims=(0, 2),
+        operand_batching_dims=(1,), scatter_indices_batching_dims=(0,))
+    return jax.lax.scatter(cache, idx, new[:, 0].astype(cache.dtype), dnums,
+                           indices_are_sorted=True, unique_indices=True,
+                           mode=jax.lax.GatherScatterMode.CLIP)
+
+
 def cache_update(cache, new, index):
     """Write one token's K or V into the cache at `index` (seq axis=1).
+
+    A tuple ``index = (layer, position)`` addresses the layer-stacked
+    cache [L,B,S,Hkv,dh] instead, whose sequence dim is local: the rows
+    are written in place (the decode step's in-place path,
+    ``transformer.decode_inplace``).
 
     On the production mesh the cache's sequence dim is sharded over "model"
     (and "data" when the batch can't shard — long_500k) whenever the KV
@@ -205,6 +229,8 @@ def cache_update(cache, new, index):
     from repro.models.sharding import active_mesh, seq_shard_layout
     from jax.sharding import PartitionSpec as P
 
+    if isinstance(index, tuple):
+        return _stacked_row_update(cache, new, *index)
     mesh = active_mesh()
     vector = bool(jnp.ndim(index))
     B, S, Hkv, dh = cache.shape
@@ -257,7 +283,7 @@ def cache_update(cache, new, index):
 def attn_apply(params, x, cfg, *, positions, mode: str,
                kv_x=None, kv_positions=None, causal: bool = True,
                cache=None, cache_index=None, use_pallas: bool = False,
-               prefix_kv=None):
+               prefix_kv=None, layer=None):
     """Unified attention entry.
 
     mode "full":   self/cross attention over x (train & prefill).
@@ -269,7 +295,11 @@ def attn_apply(params, x, cfg, *, positions, mode: str,
                    returned k/v are the full-length concatenation — so the
                    seeded cache is laid out exactly like a cold prefill's.
     mode "decode": x is [B,1,D]; cache = {"k","v"} [B,S,Hkv,dh];
-                   cache_index = scalar position of the new token.
+                   cache_index = scalar position of the new token (or
+                   per-slot positions [B]).  With ``layer`` (a traced
+                   scalar) the cache is the layer-stacked [L,B,S,Hkv,dh]
+                   one: the new rows are written into layer ``layer`` in
+                   place and attention reads that layer where it lies.
                    returns (out, new_cache).
     """
     cross = kv_x is not None
@@ -315,15 +345,19 @@ def attn_apply(params, x, cfg, *, positions, mode: str,
                                    window=cfg.sliding_window)
         y = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype), params["wo"])
         return y, new_cache
-    k_cache = cache_update(cache["k"], k.astype(cache["k"].dtype), cache_index)
-    v_cache = cache_update(cache["v"], v.astype(cache["v"].dtype), cache_index)
+    at = cache_index if layer is None else (layer, cache_index)
+    k_cache = cache_update(cache["k"], k.astype(cache["k"].dtype), at)
+    v_cache = cache_update(cache["v"], v.astype(cache["v"].dtype), at)
     # the Pallas decode kernel takes a scalar OR per-slot [B] cache length
     # (continuous batching), so both index shapes ride the TPU hot path
     if use_pallas:
         from repro.kernels.ops import decode_attention as _dec
-        out = _dec(q, k_cache, v_cache, cache_index + 1, window=cfg.sliding_window)
+        out = _dec(q, k_cache, v_cache, cache_index + 1, layer,
+                   window=cfg.sliding_window)
     else:
-        out = decode_attention_ref(q, k_cache, v_cache, cache_index + 1,
+        k_read, v_read = (k_cache, v_cache) if layer is None \
+            else (k_cache[layer], v_cache[layer])
+        out = decode_attention_ref(q, k_read, v_read, cache_index + 1,
                                    window=cfg.sliding_window)
     y = jnp.einsum("bshk,hkd->bsd", out.astype(x.dtype), params["wo"])
     return y, {"k": k_cache, "v": v_cache}

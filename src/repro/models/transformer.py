@@ -22,6 +22,7 @@ from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import mlp_apply, mlp_init, norm_apply, norm_init
+from repro.models.sharding import active_mesh, seq_shard_layout
 
 
 # ---------------------------------------------------------------------------
@@ -57,7 +58,10 @@ def init_stack(key, cfg, dtype, kind: str, n_layers: int):
 # ---------------------------------------------------------------------------
 def block_apply(params, x, cfg, *, kind: str, mode: str, positions,
                 cache=None, cache_index=None, enc_out=None, enc_positions=None,
-                causal: bool = True, use_pallas: bool = False):
+                causal: bool = True, use_pallas: bool = False, layer=None):
+    """``layer`` (decode only): ``cache`` is the whole layer-stacked
+    cache and this block reads and writes its layer ``layer`` in place
+    (see :func:`decode_inplace`)."""
     aux = jnp.zeros((), jnp.float32)
     if kind == "ssm":
         assert mode != "resume", "SSM states fold the whole prefix; resume is attention-only"
@@ -72,7 +76,8 @@ def block_apply(params, x, cfg, *, kind: str, mode: str, positions,
     if mode == "decode":
         y, new_kv = attn.attn_apply(params["attn"], h, cfg, positions=positions,
                                     mode="decode", cache=cache["self"],
-                                    cache_index=cache_index, use_pallas=use_pallas)
+                                    cache_index=cache_index, use_pallas=use_pallas,
+                                    layer=layer)
     else:
         # mode "resume": x holds only the tail rows; cache["self"] holds the
         # cached prefix K/V whose rows the tail attends over. The returned
@@ -111,11 +116,47 @@ def block_apply(params, x, cfg, *, kind: str, mode: str, positions,
 # ---------------------------------------------------------------------------
 # Stack apply via lax.scan over layers
 # ---------------------------------------------------------------------------
+def decode_inplace(caches, mesh=None) -> bool:
+    """Whether a decode step updates ``caches`` in place: a plain
+    layer-stacked ``{"self": {"k", "v"}}`` cache whose sequence dim is
+    local on ``mesh`` (``seq_shard_layout`` resolves none).  The layer
+    scan then carries the whole cache and each layer writes its new rows
+    into it and reads its layer where it lies, so a decode step moves no
+    cache bytes but the rows it writes and the keys and values it reads.
+    Int8 caches (scales beside K/V), the hybrid and cross-attention
+    caches, SSM states and the sequence-sharded layout keep the per-layer
+    slices of the scan's ``xs``/``ys``."""
+    if not (isinstance(caches, dict) and set(caches) == {"self"}
+            and isinstance(caches["self"], dict)
+            and set(caches["self"]) == {"k", "v"}):
+        return False
+    if mesh is None or "model" not in mesh.shape:
+        return True
+    _, B, S, Hkv, _ = caches["self"]["k"].shape
+    return seq_shard_layout(mesh, B, S, Hkv) is None
+
+
 def stack_apply(stacked, x, cfg, *, kind: str, mode: str, positions,
                 caches=None, cache_index=None, enc_out=None, enc_positions=None,
                 causal: bool = True, remat: bool = False, use_pallas: bool = False):
     """caches: pytree stacked on leading L axis (or None).
     Returns (x, new_caches_or_None, aux_sum)."""
+    if mode == "decode" and decode_inplace(caches, active_mesh()):
+        def step(carry, layer_in):
+            xc, aux, cache = carry
+            lp, l = layer_in
+            y, cache, a = block_apply(
+                lp, xc, cfg, kind=kind, mode=mode, positions=positions,
+                cache=cache, cache_index=cache_index, use_pallas=use_pallas,
+                layer=l)
+            return (y, aux + a, cache), None
+
+        L = jax.tree.leaves(stacked)[0].shape[0]
+        (x, aux, caches), _ = jax.lax.scan(
+            step, (x, jnp.zeros((), jnp.float32), caches),
+            (stacked, jnp.arange(L, dtype=jnp.int32)))
+        return x, caches, aux
+
     collect = caches is not None or mode == "prefill"
 
     def body(carry, layer_in):

@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models import model as M
+from repro.models.transformer import decode_inplace
 from repro.serving.spans import span
 
 
@@ -114,7 +115,10 @@ def make_decode_loop(cfg, *, macro_steps: int, eos_id: Optional[int] = None,
     bit-identical to the per-step loop.  Jit this with
     ``donate_argnums=(1, 2, 3, 4, 5)`` so the cache and the decode state
     are updated in place (the caller must treat the donated arguments as
-    consumed and only ever use the returned arrays).
+    consumed and only ever use the returned arrays).  Where
+    ``transformer.decode_inplace`` holds, each step writes its new K/V
+    rows straight into the carried stacked cache, so the cache stays one
+    buffer from the donated input to the aliased output.
     """
     use_pallas = resolve_use_pallas(use_pallas)
     eos = -1 if eos_id is None else int(eos_id)
@@ -796,6 +800,11 @@ class ContinuousServingEngine:
         # jax); capture it at construction, like the programs' tracings
         self._launcher = _DecodeLauncher(active_mesh()) \
             if self.async_dispatch else None
+        # 1 when the decode programs update the KV cache in place (the
+        # ``cache_inplace`` stat of every ``engine.launch`` span)
+        self._cache_inplace = int(decode_inplace(
+            jax.eval_shape(lambda: M.init_cache(cfg, slots, max_len)),
+            active_mesh()))
 
     def _get_loop(self, K: int):
         return _loop_program(self.cfg, self._loops, K, self.eos_id,
@@ -1439,7 +1448,8 @@ class ContinuousServingEngine:
             n_decoding = sum(1 for s in slot_states if s.busy
                              and s.remaining > 0 and not _eos_done(s))
             if n_decoding:
-                with span("engine.launch", live=n_decoding):
+                with span("engine.launch", live=n_decoding,
+                          cache_inplace=self._cache_inplace):
                     fn = self._get_wave(K, W) if W > 1 \
                         else self._get_loop(K)
                     if self._launcher is not None:

@@ -19,7 +19,8 @@ span                where                                     stats
 ``engine.prefill``  one B=1 prefill dispatch                  uid, inline
 ``engine.boundary`` pad and ``admit_boundary`` dispatch       admitted, live,
                                                               stall
-``engine.launch``   the decode macro-step's launch            live
+``engine.launch``   the decode macro-step's launch            live,
+                                                              cache_inplace
 ``engine.topup``    speculative shadow prefills               shadows
 ``engine.await``    the one host sync of a macro-step         (none)
 ==================  ========================================  ==============

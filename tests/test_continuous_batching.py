@@ -10,8 +10,11 @@ import repro.core as C
 from repro.configs.base import get_config, reduced
 from repro.core.offload import padded_quota_batch, split_sizes
 from repro.models import model as M
+from repro.models import transformer as tfm
+from repro.serving import engine as engine_mod
 from repro.serving.engine import (ContinuousServingEngine, ServeRequest,
-                                  ServingEngine)
+                                  ServingEngine, make_decode_loop,
+                                  make_serve_step)
 
 
 @pytest.fixture(scope="module")
@@ -150,6 +153,63 @@ def test_fused_macro_step_bit_identity(arch, kv_int8):
     assert stats.macro_dispatches > 0
     # the whole point: strictly fewer device->host round-trips
     assert stats.host_syncs < ref_stats.host_syncs
+
+
+@pytest.mark.parametrize("arch,kv_int8,inplace", [
+    ("llama3.2-1b", False, True),           # dense
+    ("qwen3-moe-235b-a22b", False, True),   # MoE
+    ("llama3.2-1b", True, False),           # int8 KV: scales beside K/V
+    ("zamba2-2.7b", False, False),          # hybrid shared-attention cache
+])
+def test_decode_cache_inplace(arch, kv_int8, inplace, monkeypatch):
+    """The decode step that carries the stacked cache through the layer
+    scan gives the tokens and the final cache of the per-layer
+    ``xs``/``ys`` step bit for bit, over K fused steps (live, freezing
+    and free slots) and over one static step; the engine reports which
+    path its launches take in the ``cache_inplace`` stat."""
+    cfg, params, reqs = _family_fixture(arch, kv_int8)
+    B, S, K = 4, 32, 4
+    leaves, tree = jax.tree.flatten(M.init_cache(cfg, B, S))
+    keys = jax.random.split(jax.random.PRNGKey(3), len(leaves))
+    # stale bytes everywhere, as a slot's previous occupant leaves them
+    cache = jax.tree.unflatten(tree, [
+        (jax.random.normal(k, a.shape) * 4).astype(a.dtype)
+        for k, a in zip(keys, leaves)])
+    tok = jnp.asarray([3, 7, 11, 19], jnp.int32)
+    lengths = jnp.asarray([5, 9, 0, 12], jnp.int32)
+    remaining = jnp.asarray([6, 2, 0, 1], jnp.int32)   # slot 1 and 3 freeze
+    done = jnp.asarray([False, False, True, False])    # slot 2 is free
+
+    def decode():
+        loop = jax.jit(make_decode_loop(cfg, macro_steps=K,
+                                        use_pallas=False))
+        step = jax.jit(make_serve_step(cfg, use_pallas=False))
+        return jax.device_get((
+            loop(params, cache, tok, lengths, remaining, done),
+            step(params, cache, tok[:, None], jnp.int32(7))))
+
+    assert tfm.decode_inplace(cache) == inplace
+    got = decode()
+    with monkeypatch.context() as m:
+        m.setattr(tfm, "decode_inplace", lambda *a: False)
+        ref = decode()
+    assert jax.tree.structure(got) == jax.tree.structure(ref)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+        np.testing.assert_array_equal(a, b)
+
+    launches = []
+    real_span = engine_mod.span
+
+    def record(name, **stats):
+        if name == "engine.launch":
+            launches.append(stats)
+        return real_span(name, **stats)
+    monkeypatch.setattr(engine_mod, "span", record)
+    eng = ContinuousServingEngine(cfg, params, slots=2, max_len=48,
+                                  macro_steps=K)
+    eng.run(reqs)
+    assert launches
+    assert all(st["cache_inplace"] == int(inplace) for st in launches)
 
 
 def test_fused_generate_bit_identity(small_llama):

@@ -91,6 +91,34 @@ def test_decode_attention_matches_ref(B, S, H, Hkv, dh, win, dtype):
                                np.asarray(r, np.float32), rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("L,B,S,H,Hkv,dh,win,lens", [
+    # ragged per-slot lengths: one row, a length on the 512-row block
+    # edge, a full cache whose last block overhangs it
+    (3, 3, 1100, 8, 2, 64, 0, (1, 512, 1100)),
+    # a length one past the block edge, under a sliding window
+    (2, 2, 600, 4, 4, 128, 100, (513, 37)),
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_decode_attention_reads_stacked_layer(L, B, S, H, Hkv, dh, win, lens,
+                                              dtype):
+    """Given a layer index, the kernel reads that layer of the stacked
+    [L,B,S,Hkv,dh] cache where it lies, and equals the reference on the
+    layer's own [B,S,Hkv,dh] cache, for every layer."""
+    q = jax.random.normal(KEY, (B, 1, H, dh)).astype(dtype)
+    kc = jax.random.normal(jax.random.PRNGKey(1),
+                           (L, B, S, Hkv, dh)).astype(dtype)
+    vc = jax.random.normal(jax.random.PRNGKey(2),
+                           (L, B, S, Hkv, dh)).astype(dtype)
+    cl = jnp.asarray(lens, jnp.int32)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    for layer in range(L):
+        r = decode_attention_ref(q, kc[layer], vc[layer], cl, window=win)
+        p = decode_attention(q, kc, vc, cl, jnp.int32(layer), window=win)
+        np.testing.assert_allclose(np.asarray(p, np.float32),
+                                   np.asarray(r, np.float32),
+                                   rtol=tol, atol=tol)
+
+
 def test_decode_attention_softmax_property():
     """With identical V rows the output must equal that row (softmax sums
     to 1 over the valid window)."""

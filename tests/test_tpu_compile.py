@@ -9,8 +9,10 @@ given this file loads it.  The persistent compilation cache is off
 around these compiles: a TPU executable written here cannot be read
 back without a chip.
 """
+import dataclasses
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -106,3 +108,69 @@ def test_fused_decode_loop_fits_one_chip(one_chip, monkeypatch):
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert 0 < used < HBM_BYTES, used
+
+
+def _squeezed(dims: str):
+    return tuple(int(d) for d in dims.split(",") if d and d != "1")
+
+
+def _cache_moves(hlo: str, shapes):
+    """The instructions of the optimised ``hlo`` that move a whole array
+    of one of ``shapes`` (unit dims ignored): a ``copy`` or
+    ``dynamic-slice`` by its result, a ``dynamic-update-slice`` by the
+    update it writes.  Writing one row into the stacked cache in place
+    moves a row, and is not counted."""
+    ident = r"%[\w.\-]+"
+    defs = dict(re.findall(rf"({ident}) = \w+\[([\d,]*)\]", hlo))
+    inst = re.compile(rf"({ident}) = \w+\[([\d,]*)\]\S* "
+                      rf"(copy|dynamic-slice|dynamic-update-slice)"
+                      rf"\(({ident})(?:, ({ident}))?")
+    moves = []
+    for line in hlo.splitlines():
+        m = inst.search(line)
+        if m is None:
+            continue
+        _, dims, op, _, update = m.groups()
+        if op == "dynamic-update-slice":
+            dims = defs.get(update, "")
+        if _squeezed(dims) in shapes:
+            moves.append(line.strip()[:160])
+    return moves
+
+
+@pytest.mark.parametrize("arch,layers,slots,max_len", [
+    ("olmo-1b", None, SLOTS, SMOKE_MAX_LEN),     # chip_smoke.py's program
+    ("nemotron-4-15b", 8, 8, 2120),              # nemotron15b-rag's
+])
+def test_fused_decode_loop_updates_cache_in_place(one_chip, monkeypatch,
+                                                  arch, layers, slots,
+                                                  max_len):
+    """The fused decode loop neither copies the stacked KV cache nor
+    slices a layer out of it and stacks it back: each step writes its
+    rows into the donated cache and the kernel reads each layer where it
+    lies.  So no temp buffer is cache-sized either: beyond the attention
+    projections' weights, which XLA relays out once per launch outside
+    the step loop, the temps are smaller than one layer of the cache."""
+    monkeypatch.setattr(ops, "auto_interpret", lambda: False)
+    cfg = get_config(arch)
+    if layers:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    params = _sds(jax.eval_shape(lambda k: M.init_params(cfg, k),
+                                 jax.random.PRNGKey(0)), one_chip)
+    cache = _sds(jax.eval_shape(
+        lambda: M.init_cache(cfg, slots, max_len)), one_chip)
+    vec = [jax.ShapeDtypeStruct((slots,), dt, sharding=one_chip)
+           for dt in (jnp.int32, jnp.int32, jnp.int32, jnp.bool_)]
+    loop = jax.jit(make_decode_loop(cfg, macro_steps=8, use_pallas=True),
+                   donate_argnums=(1, 2, 3, 4, 5))
+    compiled = loop.lower(params, cache, *vec).compile()
+    stacked = cache["self"]["k"]
+    assert _cache_moves(compiled.as_text(), {
+        _squeezed(",".join(map(str, stacked.shape))),
+        _squeezed(",".join(map(str, stacked.shape[1:])))}) == []
+    layer_bytes = stacked.size // stacked.shape[0] * stacked.dtype.itemsize
+    attn = params["blocks"]["attn"]
+    relaid = sum(attn[w].size * attn[w].dtype.itemsize
+                 for w in ("wq", "wk", "wv"))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp - relaid < layer_bytes, (temp, relaid, layer_bytes)
