@@ -1,12 +1,21 @@
-"""Plain float32 forward pass of the dense decoder family.
+"""The dense decoder family: its parameter leaves, its plain float32
+forward pass, and the work its serving steps need.
 
-Straight ``jax.numpy``: token embedding, then per layer a pre-norm causal
-self-attention with rotary positions (MHA or GQA) and a pre-norm MLP, each
-added to the residual stream, then the final norm and the vocabulary head.
-No cache, no kernel, no batching tricks; every product runs under
-``default_matmul_precision("highest")``.  It imports nothing of the
-program: its weights are made again here, layer by layer, from the seed
-(``bench/weights.py``), so it shares no array with the run it checks.
+This is the family module of every configuration whose file says
+``"family": "dense"`` (the contract is ``bench/reference/__init__.py``):
+
+* ``leaves`` and ``empty_subtrees``: the program's parameter tree, one
+  stack ``blocks`` of ``num_layers`` layers beside the embedding, the
+  head and the final norm; ``bench/weights.py`` hashes their elements.
+* ``logits_at``: straight ``jax.numpy``: token embedding, then per layer
+  a pre-norm causal self-attention with rotary positions (MHA or GQA) and
+  a pre-norm MLP, each added to the residual stream, then the final norm
+  and the vocabulary head.  No cache, no kernel, no batching tricks;
+  every product runs under ``default_matmul_precision("highest")``.  It
+  imports nothing of the program: its weights are made again here, layer
+  by layer, from the seed, so it shares no array with the run it checks.
+* the work counts read through ``bench/work.py``: matrix products of the
+  projections, causal attention and the head, from shapes alone.
 
 Family members, as their papers and the program's configurations state:
 
@@ -27,17 +36,68 @@ both to bfloat16 (the step below a float32 configuration).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as W
+from bench.work import ITEMSIZE
 
 EPS = 1e-5
+STACK = "blocks"
 
 
+# --------------------------------------------------------------------------
+# the program's parameter tree
+# --------------------------------------------------------------------------
+def leaves(cfg: dict) -> List[W.Leaf]:
+    """The dense family's parameter leaves, as the program lays them out."""
+    D, H, Hkv, dh = cfg["d_model"], cfg["num_heads"], cfg["num_kv_heads"], \
+        cfg["head_dim"]
+    F, V, L = cfg["d_ff"], cfg["vocab_size"], cfg["num_layers"]
+    dt = cfg["dtype"]
+
+    def layer(path, shape, mean, std, dtype=dt):
+        return W.Leaf(f"{STACK}/{path}", shape, mean, std, dtype, STACK, L)
+    out = [W.Leaf("embed/table", (V, D), 0.0, 0.02, dt)]
+    if not cfg["tie_embeddings"]:
+        out.append(W.Leaf("lm_head/table", (V, D), 0.0, 0.02, dt))
+    if cfg["norm_type"] == "layernorm":
+        norm = (("scale", 1.0), ("bias", 0.0))
+        out += [W.Leaf(f"final_norm/{p}", (D,), m, 0.1, "float32")
+                for p, m in norm]
+        out += [layer(f"{n}/{p}", (D,), m, 0.1, "float32")
+                for n in ("ln1", "ln2") for p, m in norm]
+    elif cfg["norm_type"] != "nonparametric":
+        raise ValueError(f"norm_type {cfg['norm_type']!r} is not dense-LN")
+    out += [
+        layer("attn/wq", (D, H, dh), 0.0, D ** -0.5),
+        layer("attn/wk", (D, Hkv, dh), 0.0, D ** -0.5),
+        layer("attn/wv", (D, Hkv, dh), 0.0, D ** -0.5),
+        layer("attn/wo", (H, dh, D), 0.0, (H * dh) ** -0.5),
+    ]
+    if cfg["mlp_type"] == "swiglu":
+        out.append(layer("mlp/w_gate", (D, F), 0.0, D ** -0.5))
+    elif cfg["mlp_type"] != "squared_relu":
+        raise ValueError(f"mlp_type {cfg['mlp_type']!r} not supported")
+    out += [
+        layer("mlp/w_up", (D, F), 0.0, D ** -0.5),
+        layer("mlp/w_down", (F, D), 0.0, F ** -0.5),
+    ]
+    return out
+
+
+def empty_subtrees(cfg: dict) -> List[str]:
+    """The norms' subtrees, which stay empty for a norm without scale or
+    bias."""
+    return ["final_norm", f"{STACK}/ln1", f"{STACK}/ln2"]
+
+
+# --------------------------------------------------------------------------
+# the plain reference
+# --------------------------------------------------------------------------
 def _fake_quant(x, axes, quant: Optional[str]):
     """Round ``x`` to a quantised grid scaled by its absmax over ``axes``."""
     if quant is None:
@@ -163,7 +223,7 @@ def logits_at(cfg: dict, seed: int, tokens: np.ndarray, rows: np.ndarray,
     if not cfg["tie_embeddings"]:
         del embed
     for layer in range(cfg["num_layers"]):
-        w = W.layer_weights(cfg, seed, layer)
+        w = W.layer_weights(cfg, seed, layer, stack=STACK)
         xs = [_layer(x, w, cfg_items=items, quant=quant) for x in xs]
         del w
     x = jnp.concatenate(xs, axis=0)
@@ -177,3 +237,54 @@ def logits_at(cfg: dict, seed: int, tokens: np.ndarray, rows: np.ndarray,
     table = embed if cfg["tie_embeddings"] \
         else W.global_weight(cfg, seed, "lm_head/table")
     return _head(x, table, final, cfg_items=items, quant=quant)
+
+
+# --------------------------------------------------------------------------
+# work counts (bench/work.py): matrix products at 2 operations per
+# multiply-add; norms, rotary and softmax left out
+# --------------------------------------------------------------------------
+def layer_params(cfg: dict) -> int:
+    """Weights of one decoder layer's projections."""
+    D, H, Hkv, dh, F = (cfg["d_model"], cfg["num_heads"],
+                        cfg["num_kv_heads"], cfg["head_dim"], cfg["d_ff"])
+    attn = D * H * dh * 2 + D * Hkv * dh * 2
+    mlp = (3 if cfg["mlp_type"] == "swiglu" else 2) * D * F
+    return attn + mlp
+
+
+def head_params(cfg: dict) -> int:
+    return cfg["vocab_size"] * cfg["d_model"]
+
+
+def _attn_flops_per_key(cfg: dict) -> int:
+    """Q·K and P·V of one query against one key, over all layers."""
+    return 4 * cfg["num_layers"] * cfg["num_heads"] * cfg["head_dim"]
+
+
+def prefill_flops(cfg: dict, P: int) -> float:
+    """One prompt of ``P`` tokens: projections of every token, causal
+    attention (query i sees keys 0..i) and the last position's head."""
+    proj = 2.0 * cfg["num_layers"] * layer_params(cfg) * P
+    attn = _attn_flops_per_key(cfg) * P * (P + 1) / 2.0
+    return proj + attn + 2.0 * head_params(cfg)
+
+
+def decode_token_flops(cfg: dict, n_keys) -> np.ndarray:
+    """One decoded token at live length ``n_keys`` (array or scalar)."""
+    n = np.asarray(n_keys, np.float64)
+    proj = 2.0 * (cfg["num_layers"] * layer_params(cfg) + head_params(cfg))
+    return proj + _attn_flops_per_key(cfg) * n
+
+
+def decode_attn_work(cfg: dict, n_keys) -> dict:
+    """The decode-attention kernel's work for one token at live length
+    ``n_keys``, over all layers: operations, and the bytes of the live K/V
+    rows plus the query read and the output written.  Independent of the
+    cache's allocated length."""
+    n = np.asarray(n_keys, np.float64)
+    L, H, Hkv, dh = (cfg["num_layers"], cfg["num_heads"],
+                     cfg["num_kv_heads"], cfg["head_dim"])
+    it = ITEMSIZE[cfg["dtype"]]
+    kv = 2.0 * n * Hkv * dh * it
+    qo = 2.0 * H * dh * it
+    return {"flops": _attn_flops_per_key(cfg) * n, "bytes": L * (kv + qo)}

@@ -7,11 +7,15 @@
 A cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a model
 configuration (``bench/configs/<config>.json``, its correctness limits in
 ``bench/limits/<config>.json``) and a traffic mix
-(``bench/traffic/<traffic>.json``).  One process holds the cell's chips
+(``bench/traffic/<traffic>.json``).  The configuration's ``family``
+chooses its family module, ``bench/reference/<family>.py`` (the contract
+is in ``bench/reference/__init__.py``): the leaves of its weights, its
+plain reference and its work counts.  One process holds the cell's chips
 and, in order:
 
 1. makes the configuration's weights from ``--seed`` on the device, in the
-   type they are served in (``bench/weights.py``);
+   type they are served in (``bench/weights.py``, with the family's
+   leaves);
 2. builds the deployment users run: ``HeteroRuntime`` behind
    ``ServingFrontend``, with the topology, slots and split the mix fixes;
 3. warms the cell's own programs (B=1 prefill at the mix's prompt length,
@@ -20,15 +24,16 @@ and, in order:
 4. sends the mix's requests through ``ServingFrontend.submit`` for
    ``--seconds``, each at its due time (open loop), and follows each to
    the last token of its ``TokenStream``;
-5. checks what the window served against the plain float32 reference
-   (``bench/reference/dense.py``) once the program's state is freed, and
-   prints one JSON line.
+5. checks what the window served against the family's plain float32
+   reference once the program's state is freed, and prints one JSON
+   line.
 
 ``--trace 0`` reports the end-to-end metrics (client-side clocks);
 ``--trace 1`` traces the end of the window with the profiler and
 reports the per-layer metrics, each read by ``bench/metrics/<name>.py``.
-A run on anything but the cell's TPU chips, or on a chip missing from
-``bench/peaks.json``, exits with code 2 and prints no result.
+A run on anything but the cell's TPU chips, on a chip missing from
+``bench/peaks.json``, or of a configuration whose family has no module,
+exits with code 2 and prints no result.
 """
 from __future__ import annotations
 
@@ -57,7 +62,9 @@ for p in (os.path.join(ROOT, "src"), ROOT):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+from bench import reference as FAM  # noqa: E402
 from bench import traffic as TR  # noqa: E402
+from bench import work  # noqa: E402
 
 # requests still streaming this long after the window closes never came
 GRACE_S = 60.0
@@ -88,7 +95,10 @@ def load_json(path: str) -> dict:
 
 
 def load_cell(workload: str, root: str = ROOT) -> dict:
-    """The cell's entry, its configuration file and its traffic mix."""
+    """The cell's entry, its configuration file and its traffic mix, all
+    from the checkout ``root``.  The configuration's family module is
+    loaded from there too, and its file kept in the spec
+    (``family_file``)."""
     try:
         bench = load_json(os.path.join(root, "BENCHMARK.json"))
         cell = next(w for w in bench["workloads"] if w["name"] == workload)
@@ -101,6 +111,16 @@ def load_cell(workload: str, root: str = ROOT) -> dict:
                                      cell["traffic"] + ".json"))
     except (OSError, StopIteration, KeyError, ValueError) as e:
         raise Refused(f"cannot load cell {workload!r}: {e!r}")
+    try:
+        path = FAM.path_of(str(spec.get("family")), root)
+    except ValueError as e:
+        raise Refused(f"cannot load cell {workload!r}: {e}")
+    if not os.path.isfile(path):
+        raise Refused(f"configuration {entry['name']!r} names family "
+                      f"{spec.get('family')!r}, and "
+                      f"{os.path.relpath(path, root)} does not exist")
+    FAM.load(path)
+    spec["family_file"] = path
     metrics = [m for m in bench["end_to_end"] + bench["per_layer"]
                if workload in m.get("workloads", [workload])]
     return {"cell": cell, "spec": spec, "mix": mix,
@@ -428,6 +448,15 @@ def load_reader(name: str):
     return mod.read
 
 
+def read_layer_metric(name: str, ctx) -> Optional[float]:
+    """A per-layer metric's value, or None where its reader finds nothing
+    to read or needs a work count that the family does not give."""
+    try:
+        return load_reader(name)(ctx)
+    except work.Uncounted:
+        return None
+
+
 # --------------------------------------------------------------------------
 # correctness
 # --------------------------------------------------------------------------
@@ -455,7 +484,7 @@ def served_gaps(spec: dict, seed: int, sample: List[Record],
     the gap (reference best minus reference logit of the served token) of
     each served token, and for each lower precision in ``quants`` the
     gap of the token that precision puts first."""
-    from bench.reference import dense
+    fam = FAM.family(spec)
     P = len(sample[0].req.prompt)
     T = P + max(len(r.tokens) for r in sample) - 1
     seqs = np.zeros((len(sample), T), np.int32)
@@ -468,12 +497,12 @@ def served_gaps(spec: dict, seed: int, sample: List[Record],
         served += r.tokens
     rows = np.asarray(rows)
     served = np.asarray(served)
-    ref = np.asarray(dense.logits_at(spec, seed, seqs, rows))
+    ref = np.asarray(fam.logits_at(spec, seed, seqs, rows))
     best = ref.max(axis=-1)
     out = {"served": best - ref[np.arange(len(served)), served],
            "n_tokens": len(served)}
     for q in quants:
-        low = np.asarray(dense.logits_at(spec, seed, seqs, rows, quant=q))
+        low = np.asarray(fam.logits_at(spec, seed, seqs, rows, quant=q))
         pick = low.argmax(axis=-1)
         out[q] = best - ref[np.arange(len(pick)), pick]
     return out
@@ -603,7 +632,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                       waves=dep.probe.waves, red=red,
                       window=(tracer.t0, tracer.t1))
         for m in cell["per_layer"]:
-            v = load_reader(m["name"])(ctx)
+            v = read_layer_metric(m["name"], ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
         device["busy_s"] = red.busy_s()

@@ -1,24 +1,30 @@
-"""Seeded weights of a dense decoder, made on the device in one jitted call.
+"""Seeded weights of a model, made on the device in one jitted call.
 
 Every element is a counter-based hash of (seed, leaf, layer, index), mapped
 to a uniform value with the leaf's mean and standard deviation.  Integer
 arithmetic makes the values the same on every backend and lets any single
-layer be made again alone, bit for bit: the plain reference
-(``bench/reference/dense.py``) regenerates one layer at a time and never
-reads the arrays handed to the program.
+layer be made again alone, bit for bit: a family's plain reference
+regenerates one layer at a time and never reads the arrays handed to the
+program.
 
-``leaves(cfg)`` lists the leaves of the program's parameter tree for the
-dense family (path, per-layer shape, mean, std, dtype, stacked);
-``program_params`` builds that tree, ``layer_weights`` one layer of it.
+This module holds the hashing only.  Which leaves a tree has comes from
+the configuration's family module (``bench/reference/<family>.py``,
+``leaves`` and ``empty_subtrees``): each leaf's path, one layer's shape,
+mean, std and dtype, and for a stacked leaf the stack it belongs to and
+that stack's depth, so stacks of different depths sit in one tree.
+``program_params`` builds the whole tree, ``layer_weights`` one layer of
+a named stack, ``global_weight`` one unstacked leaf.
 """
 from __future__ import annotations
 
 import zlib
-from typing import Dict, List, NamedTuple, Tuple
+from typing import Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import reference
 
 _M1 = np.uint32(0x7FEB352D)
 _M2 = np.uint32(0x846CA68B)
@@ -26,11 +32,12 @@ _M2 = np.uint32(0x846CA68B)
 
 class Leaf(NamedTuple):
     path: str                 # "/"-joined keys of the program's tree
-    shape: Tuple[int, ...]    # one layer's shape (stacked leaves add [L])
+    shape: Tuple[int, ...]    # one layer's shape (stacked leaves add [depth])
     mean: float
     std: float
     dtype: str
-    stacked: bool
+    stack: str = ""           # a stacked leaf's stack: a prefix of ``path``
+    depth: int = 0            # layers in that stack; 0 for unstacked
 
 
 def seed_words(seed: int) -> np.ndarray:
@@ -45,40 +52,6 @@ def _mix(x):
     x = x ^ (x >> 15)
     x = x * _M2
     return x ^ (x >> 16)
-
-
-def leaves(cfg: dict) -> List[Leaf]:
-    """The dense family's parameter leaves, as the program lays them out."""
-    D, H, Hkv, dh = cfg["d_model"], cfg["num_heads"], cfg["num_kv_heads"], \
-        cfg["head_dim"]
-    F, V = cfg["d_ff"], cfg["vocab_size"]
-    dt = cfg["dtype"]
-    out = [Leaf("embed/table", (V, D), 0.0, 0.02, dt, False)]
-    if not cfg["tie_embeddings"]:
-        out.append(Leaf("lm_head/table", (V, D), 0.0, 0.02, dt, False))
-    norms = ["final_norm", "blocks/ln1", "blocks/ln2"]
-    if cfg["norm_type"] == "layernorm":
-        for n in norms:
-            st = n.startswith("blocks")
-            out.append(Leaf(f"{n}/scale", (D,), 1.0, 0.1, "float32", st))
-            out.append(Leaf(f"{n}/bias", (D,), 0.0, 0.1, "float32", st))
-    elif cfg["norm_type"] != "nonparametric":
-        raise ValueError(f"norm_type {cfg['norm_type']!r} is not dense-LN")
-    out += [
-        Leaf("blocks/attn/wq", (D, H, dh), 0.0, D ** -0.5, dt, True),
-        Leaf("blocks/attn/wk", (D, Hkv, dh), 0.0, D ** -0.5, dt, True),
-        Leaf("blocks/attn/wv", (D, Hkv, dh), 0.0, D ** -0.5, dt, True),
-        Leaf("blocks/attn/wo", (H, dh, D), 0.0, (H * dh) ** -0.5, dt, True),
-    ]
-    if cfg["mlp_type"] == "swiglu":
-        out.append(Leaf("blocks/mlp/w_gate", (D, F), 0.0, D ** -0.5, dt, True))
-    elif cfg["mlp_type"] != "squared_relu":
-        raise ValueError(f"mlp_type {cfg['mlp_type']!r} not supported")
-    out += [
-        Leaf("blocks/mlp/w_up", (D, F), 0.0, D ** -0.5, dt, True),
-        Leaf("blocks/mlp/w_down", (F, D), 0.0, F ** -0.5, dt, True),
-    ]
-    return out
 
 
 def _values(words, leaf: Leaf, layer, shape):
@@ -109,13 +82,15 @@ def _set(tree: dict, path: str, value) -> None:
 
 
 def _build(words, cfg: dict) -> dict:
-    L = cfg["num_layers"]
-    tree: Dict = {"final_norm": {}, "blocks": {"ln1": {}, "ln2": {}}}
-    for leaf in leaves(cfg):
-        if leaf.stacked:
-            shape = (L,) + leaf.shape
+    fam = reference.family(cfg)
+    tree: Dict = {}
+    for path in fam.empty_subtrees(cfg):
+        _set(tree, path, {})
+    for leaf in fam.leaves(cfg):
+        if leaf.depth:
+            shape = (leaf.depth,) + leaf.shape
             layer = jax.lax.broadcasted_iota(
-                jnp.uint32, (L,) + (1,) * len(leaf.shape), 0)
+                jnp.uint32, (leaf.depth,) + (1,) * len(leaf.shape), 0)
         else:
             shape, layer = leaf.shape, jnp.uint32(0)
         _set(tree, leaf.path, _values(words, leaf, layer, shape))
@@ -131,23 +106,28 @@ def program_params(cfg: dict, seed: int, device=None):
     return jax.block_until_ready(fn(w))
 
 
-def layer_weights(cfg: dict, seed: int, layer: int) -> dict:
-    """One layer's stacked leaves (without the [L] axis), made alone in
-    one jitted call."""
-    return _layer_jit(cfg)(jnp.asarray(seed_words(seed)), jnp.uint32(layer))
+def layer_weights(cfg: dict, seed: int, layer: int, *, stack: str) -> dict:
+    """One layer of the stack ``stack``: its leaves without the [depth]
+    axis, keyed by their paths below the stack's prefix, made alone in one
+    jitted call."""
+    return _layer_jit(cfg, stack)(jnp.asarray(seed_words(seed)),
+                                  jnp.uint32(layer))
 
 
 _LAYER_JITS: Dict[tuple, object] = {}
 
 
-def _layer_jit(cfg: dict):
-    ls = tuple(lf for lf in leaves(cfg) if lf.stacked)
+def _layer_jit(cfg: dict, stack: str):
+    ls = tuple(lf for lf in reference.family(cfg).leaves(cfg)
+               if lf.depth and lf.stack == stack)
+    if not ls:
+        raise KeyError(f"no stack {stack!r} in {cfg['name']!r}")
     fn = _LAYER_JITS.get(ls)
     if fn is None:
         def one_layer(w, layer):
             out: Dict = {}
             for leaf in ls:
-                _set(out, leaf.path[len("blocks/"):],
+                _set(out, leaf.path[len(stack) + 1:],
                      _values(w, leaf, layer, leaf.shape))
             return out
         fn = _LAYER_JITS[ls] = jax.jit(one_layer)
@@ -156,6 +136,7 @@ def _layer_jit(cfg: dict):
 
 def global_weight(cfg: dict, seed: int, path: str):
     """An unstacked leaf (embedding, head or final norm), made alone."""
-    leaf = next(lf for lf in leaves(cfg) if lf.path == path)
+    leaf = next(lf for lf in reference.family(cfg).leaves(cfg)
+                if lf.path == path and not lf.depth)
     return jax.jit(lambda w: _values(w, leaf, jnp.uint32(0), leaf.shape))(
         jnp.asarray(seed_words(seed)))
